@@ -26,7 +26,8 @@ from repro.kernels import ops
 # CPU-calibrated "device" (1 core): ~50 GFLOP/s, ~20 GB/s effective.
 CPU_DEV = pm.TpuSpec(name="host-cpu", peak_flops_bf16=5e10,
                      peak_flops_f32=5e10, vpu_flops_f32=5e10,
-                     hbm_bw=2e10, ici_bw=1e12, vmem_bytes=2 ** 21,
+                     hbm_bw=2e10, ici_bw=1e12,
+                     vmem_capacity=24 * 2 ** 20,
                      hbm_bytes=2 ** 34, tdp_watts=65.0)
 
 GRID = (512, 2048)

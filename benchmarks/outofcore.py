@@ -117,7 +117,6 @@ def run(smoke: bool = False) -> list[dict]:
     bx, bt = 128, 2
     spec = diffusion(2, 1)
     backend = ops.resolve_backend("auto")
-    interpret = backend == "interpret"
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     cells = float(np.prod(shape))
@@ -145,7 +144,7 @@ def run(smoke: bool = False) -> list[dict]:
     tile_list = sorted(set(tiles) | ({auto.tile} if auto else set()))
     for tile in tile_list:
         run_tile = lambda t=tile: stencil_run_outofcore(
-            x, spec, n_steps, bx=bx, bt=bt, interpret=interpret, tile=t)
+            x, spec, n_steps, bx=bx, bt=bt, backend=backend, tile=t)
         t_oc = _time(run_tile)
         got = run_tile()
         np.testing.assert_array_equal(
@@ -156,7 +155,7 @@ def run(smoke: bool = False) -> list[dict]:
         # the measured-overlap accounting.
         serial = _serial_metrics(
             lambda m, t=tile: stencil_run_outofcore(
-                x, spec, n_steps, bx=bx, bt=bt, interpret=interpret,
+                x, spec, n_steps, bx=bx, bt=bt, backend=backend,
                 tile=t, depth=1, metrics=m))
         transfer_s = serial["upload_s"] + serial["readback_s"]
         f_serial, f_ovl = measured_exposed_fractions(t_oc, serial,
@@ -200,11 +199,11 @@ def run(smoke: bool = False) -> list[dict]:
     tile_k = auto.tile if auto else tile_list[0]
     kmet: dict = {}
     run_k = lambda m=None: stencil_run_outofcore(  # noqa: E731
-        x, spec, n_steps, bx=bx, bt=bt, interpret=interpret,
+        x, spec, n_steps, bx=bx, bt=bt, backend=backend,
         tile=tile_k, pipeline="kernel",
         metrics=m if m is not None else None)
     got_k = stencil_run_outofcore(
-        x, spec, n_steps, bx=bx, bt=bt, interpret=interpret,
+        x, spec, n_steps, bx=bx, bt=bt, backend=backend,
         tile=tile_k, pipeline="kernel", metrics=kmet)
     np.testing.assert_array_equal(
         got_k, want,
@@ -236,7 +235,7 @@ def run(smoke: bool = False) -> list[dict]:
             continue
         smet: dict = {}
         run_s = lambda m=None, n=nd: stencil_run_outofcore(  # noqa: E731
-            x, spec, n_steps, bx=bx, bt=bt, interpret=interpret,
+            x, spec, n_steps, bx=bx, bt=bt, backend=backend,
             tile=tile_k, n_devices=n, metrics=m)
         got_s = run_s(smet)
         np.testing.assert_array_equal(

@@ -50,7 +50,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
     picked = args.only.split(",") if args.only else list(SUITES)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.kernels import autotune
+    enable_compile_cache()
     if args.retune:
         autotune.clear_cache()
     print(f"# autotune cache: {autotune.cache_path()}", file=sys.stderr)

@@ -199,7 +199,7 @@ def _overlap_rows(smoke: bool) -> list[dict]:
 
     shard = lambda ov: halo.stencil_run_sharded(  # noqa: E731
         x, spec, n_steps, n_devices=n, bx=128, bt=bt,
-        interpret=True, overlap=ov)
+        backend="interpret", overlap=ov)
     a, b = shard(True), shard(False)
     np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b),

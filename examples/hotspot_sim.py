@@ -4,6 +4,9 @@ blocked stencil accelerator, with the autotuner (model prior ->
 measured ground truth -> disk cache) choosing the configuration.
 
   PYTHONPATH=src python examples/hotspot_sim.py [--steps 200]
+
+The main run takes ``backend="auto"``: the compiled kernels on a TPU,
+the Pallas interpreter elsewhere (keep the grid small there).
 """
 import argparse
 import time
@@ -13,8 +16,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.apps import hotspot
+from repro.compile_cache import enable_compile_cache
 from repro.core.perf_model import V5E, stencil_roofline
-from repro.kernels import autotune
+from repro.kernels import autotune, ops
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=200)
@@ -27,7 +33,8 @@ spec = hotspot.spec_of(params)
 temp, power = hotspot.random_problem(jax.random.PRNGKey(0), args.h, args.w)
 
 # autotuned blocking choice (the thesis's §5.4 tuning flow)
-tuned = autotune.plan((args.h, args.w), spec, backend="reference",
+backend = ops.resolve_backend("auto")
+tuned = autotune.plan((args.h, args.w), spec, backend=backend,
                       n_steps=args.steps)
 plan = tuned.block_plan
 terms = stencil_roofline(plan, args.steps, tpu=V5E)
@@ -38,11 +45,12 @@ print(f"grid {args.h}x{args.w}, {args.steps} steps; autotuner chose "
 
 t0 = time.perf_counter()
 out = hotspot.hotspot_blocked(temp, power, args.steps, bt=plan.bt,
-                              bx=plan.bx, backend="reference")
+                              bx=plan.bx, backend=backend)
 out.block_until_ready()
 dt = time.perf_counter() - t0
 cells = args.h * args.w * args.steps
-print(f"host run: {dt:.2f}s  ({cells/dt/1e6:.1f} MCell-updates/s on CPU)")
+print(f"{backend} run on {jax.devices()[0].device_kind}: {dt:.2f}s "
+      f"({cells/dt/1e6:.1f} MCell-updates/s, compile included)")
 
 # physical sanity + agreement with the per-step reference on a window
 ref_small = hotspot.hotspot_reference(temp[:64, :256], power[:64, :256], 8)

@@ -1,123 +1,69 @@
-"""JAX version-compatibility shim — the single place this repo touches
-``jax.experimental``.
+"""The single place this repo touches ``jax.experimental``.
 
-Policy (see README §Compat): every symbol whose home or spelling has
-drifted across jax releases is resolved *here, once*, and the rest of
-the codebase imports it from ``repro.compat``. The suite runs on
-jax 0.4.3x through current; known drift handled:
+Written for the installed jax (0.9.x): every experimental symbol the
+engine needs is imported here once and re-exported, so a later jax
+that moves or renames one is fixed in this file only:
 
-  * ``pallas`` / ``pallas.tpu`` module homes (re-exported as ``pl`` /
-    ``pltpu``);
-  * the TPU compiler-params class: ``pltpu.TPUCompilerParams`` (0.4.x)
-    vs ``pltpu.CompilerParams`` (renamed in 0.5+), constructed through
-    :func:`tpu_compiler_params` which also drops kwargs a given version
-    does not know (e.g. ``dimension_semantics`` spelling changes);
-  * ``shard_map``: ``jax.experimental.shard_map.shard_map`` (0.4.x) vs
-    public ``jax.shard_map`` (0.5+), including the ``check_rep`` ->
-    ``check_vma`` keyword rename, via :func:`shard_map`;
-  * the Pallas GPU (Triton) lowering: ``pallas.triton`` (new) vs
-    ``pallas.gpu`` (0.4.x) vs absent (CPU-only builds), re-exported as
-    ``pltriton`` (``None`` when absent) with
-    :func:`gpu_compiler_params` / :func:`compiler_params_for` /
-    :func:`available_backends` as the backend-portability surface the
-    engine builds on (docs/portability.md).
+  * ``pallas`` / ``pallas.tpu`` / ``pallas.triton`` module homes
+    (re-exported as ``pl`` / ``pltpu`` / ``pltriton``);
+  * the compiler-params constructors :func:`tpu_compiler_params`,
+    :func:`gpu_compiler_params` and :func:`compiler_params_for`, which
+    reject keywords the params class does not know instead of dropping
+    them (a misspelt ``vmem_limit_bytes`` must fail loudly, not vanish);
+  * ``shard_map`` and ``axis_size`` (the public ``jax`` names);
+  * :func:`platform` / :func:`available_backends`, the
+    backend-portability surface the engine builds on
+    (docs/portability.md).
 
 Keep this module dependency-light: importing it must never require a
 TPU, and must stay side-effect free.
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
-import inspect
-from typing import Any, Callable
+from typing import Any
 
 import jax
 
-# --------------------------------------------------------------------------
-# Pallas module homes. jax.experimental is the only sanctioned import site.
-# --------------------------------------------------------------------------
 from jax.experimental import pallas as pl                   # noqa: F401
 from jax.experimental.pallas import tpu as pltpu            # noqa: F401
 
-# The GPU (Triton) lowering has moved homes across releases —
-# ``jax.experimental.pallas.triton`` (new) vs ``.gpu`` (0.4.x) — and
-# may be absent entirely (CPU-only builds). Resolved here once, like
-# everything else; ``None`` means "no GPU pallas in this install" and
-# every GPU-backend entry point degrades to a loud, catchable error
-# rather than an import crash (docs/portability.md).
+# The GPU (Triton) lowering may be absent from a CPU-only build;
+# ``None`` means "no GPU pallas in this install" and every GPU-backend
+# entry point degrades to a loud, catchable error rather than an import
+# crash (docs/portability.md).
 try:
     from jax.experimental.pallas import triton as pltriton  # noqa: F401
 except ImportError:                                # pragma: no cover
-    try:
-        from jax.experimental.pallas import gpu as pltriton  # noqa: F401
-    except ImportError:
-        pltriton = None
+    pltriton = None
 
-__all__ = ["pl", "pltpu", "pltriton", "jax_version",
-           "tpu_compiler_params", "gpu_compiler_params",
-           "compiler_params_for", "has_gpu_pallas", "platform",
-           "available_backends", "shard_map", "axis_size"]
+__all__ = ["pl", "pltpu", "pltriton", "tpu_compiler_params",
+           "gpu_compiler_params", "compiler_params_for", "has_gpu_pallas",
+           "platform", "available_backends", "shard_map", "axis_size"]
 
-
-def jax_version() -> tuple[int, ...]:
-    return tuple(int(p) for p in jax.__version__.split(".")[:3])
-
-
-# --------------------------------------------------------------------------
-# TPU compiler params
-# --------------------------------------------------------------------------
-
-def _compiler_params_cls():
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise ImportError(
-        "pallas TPU compiler-params class not found in this jax version; "
-        "extend repro.compat._compiler_params_cls")
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def tpu_compiler_params(**kwargs: Any):
-    """Construct the TPU compiler-params object, whatever it is called.
-
-    Unknown keywords are dropped (with the value silently ignored) so a
-    caller can request e.g. ``dimension_semantics`` uniformly and still
-    run on a jax whose params class predates/renamed that field.
-    """
-    return _filtered_construct(_compiler_params_cls(), kwargs)
-
-
-def _filtered_construct(cls, kwargs):
-    """Instantiate a compiler-params class, dropping unknown kwargs."""
-    if dataclasses.is_dataclass(cls):
-        known = {f.name for f in dataclasses.fields(cls)}
-    else:  # pragma: no cover - non-dataclass future versions
-        known = set(inspect.signature(cls).parameters)
-    return cls(**{k: v for k, v in kwargs.items() if k in known})
+    """``pltpu.CompilerParams(**kwargs)``; an unknown keyword raises
+    ``TypeError``."""
+    return pltpu.CompilerParams(**kwargs)
 
 
 def gpu_compiler_params(**kwargs: Any):
-    """Construct the Triton compiler-params object, whatever its name.
-
-    Mirrors :func:`tpu_compiler_params`: unknown keywords are dropped so
-    callers can request e.g. ``num_warps`` / ``num_stages`` uniformly.
-    Raises ``ImportError`` when this jax has no GPU pallas at all.
-    """
+    """``pltriton.CompilerParams(**kwargs)``; an unknown keyword raises
+    ``TypeError``. Raises ``ImportError`` when this jax has no GPU
+    pallas at all."""
     if pltriton is None:
         raise ImportError(
             "this jax install has no Pallas GPU (Triton) lowering; "
             "the 'gpu' engine backend is unavailable "
             "(see docs/portability.md)")
-    for name in ("CompilerParams", "TritonCompilerParams",
-                 "GPUCompilerParams"):
-        cls = getattr(pltriton, name, None)
-        if cls is not None:
-            return _filtered_construct(cls, kwargs)
-    return None   # pragma: no cover - very old pallas.gpu: params-free
+    return pltriton.CompilerParams(**kwargs)
 
 
-def compiler_params_for(backend: str, n_grid: int = 1):
+def compiler_params_for(backend: str, n_grid: int = 1,
+                        vmem_limit_bytes: int | None = None):
     """Platform-appropriate ``pallas_call`` compiler params.
 
     ``backend`` is a *resolved* engine backend (``kernels.ops``
@@ -129,11 +75,15 @@ def compiler_params_for(backend: str, n_grid: int = 1):
     revolving/streaming kernels rely on), which has no Triton analog:
     GPU grid dimensions are parallel, which is exactly why the engine
     restricts the GPU backend to scratch-free variants.
+    ``vmem_limit_bytes`` is the kernel's scoped-VMEM limit on TPU (the
+    engine passes its modeled footprint, ``core.blocking.vmem_limit``;
+    without it Mosaic applies its small default scoped limit).
     """
     if backend == "gpu":
         return gpu_compiler_params()
     return tpu_compiler_params(
-        dimension_semantics=("arbitrary",) * n_grid)
+        dimension_semantics=("arbitrary",) * n_grid,
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def has_gpu_pallas() -> bool:
@@ -163,51 +113,3 @@ def available_backends() -> tuple[str, ...]:
     elif plat == "gpu" and has_gpu_pallas():
         out.append("gpu")
     return tuple(out)
-
-
-def axis_size(axis_name) -> int:
-    """``lax.axis_size`` (new jax) with the classic ``psum(1, name)``
-    constant-folding idiom as the 0.4.x fallback."""
-    impl = getattr(jax.lax, "axis_size", None)
-    if impl is not None:
-        return impl(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# --------------------------------------------------------------------------
-# shard_map
-# --------------------------------------------------------------------------
-
-def _resolve_shard_map() -> tuple[Callable, str | None]:
-    """Return (impl, replication-check kwarg name or None)."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    params = set(inspect.signature(impl).parameters)
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return impl, name
-    return impl, None
-
-
-def shard_map(f: Callable | None = None, *, mesh, in_specs, out_specs,
-              check_vma: bool | None = None,
-              check_rep: bool | None = None, **kwargs: Any):
-    """Version-stable ``shard_map``.
-
-    Accepts either ``check_vma`` (0.5+ spelling) or ``check_rep`` (0.4.x
-    spelling) and forwards under whichever name the installed jax
-    understands. Usable bare or as ``functools.partial(shard_map,
-    mesh=..., ...)`` like the underlying transform.
-    """
-    if f is None:
-        return functools.partial(
-            shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, check_rep=check_rep, **kwargs)
-    impl, check_kw = _resolve_shard_map()
-    flag = check_vma if check_vma is not None else check_rep
-    call_kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kwargs)
-    if flag is not None and check_kw is not None:
-        call_kw[check_kw] = flag
-    return impl(f, **call_kw)

@@ -167,24 +167,130 @@ class BlockPlan:
         per_slice = self.cells // self.leading
         return 2 * self.halo * per_slice * self.itemsize
 
-    def vmem_bytes(self) -> int:
-        """Per-core VMEM working set of the Pallas kernel."""
-        if self.spec.dims == 2:
-            # Per streamed operand (grid + each aux): 3 input tiles +
-            # a window; plus the output tile (all full-height).
-            per_operand = 3 * self.bx + self.window_width
-            cols = per_operand * (1 + self.n_aux) + self.bx
-            return self.padded_rows * cols * self.itemsize
-        # 3D: bt stage windows of (2r+1) planes + 3 input planes +
-        # output, plus a (bt*r + 1)-deep rolling plane buffer per aux
-        # operand (engine._kernel_3d_stream).
-        planes = self.bt * (2 * self.spec.radius + 1) + 4
-        planes += self.n_aux * (self.bt * self.spec.radius + 1)
-        return planes * self.padded_rows * self.window_width * self.itemsize
+    def vmem_bytes(self, variant: str = "revolving") -> int:
+        """Per-core VMEM the Pallas kernel of ``variant`` allocates (see
+        :func:`kernel_vmem_bytes`). The default, revolving, is the
+        smaller 2D variant; the 3D kernel ignores ``variant``."""
+        return kernel_vmem_bytes(
+            self.spec, rows=self.rows, bx=self.bx, bt=self.bt,
+            halo=self.halo, n_streams=1 + self.n_aux, variant=variant,
+            itemsize=self.itemsize)
 
     def sweeps(self, n_steps: int) -> int:
         """Grid passes needed for ``n_steps`` total time steps."""
         return math.ceil(n_steps / self.bt)
+
+
+# ---------------------------------------------------------------------------
+# VMEM model. Mosaic keeps every full-height value of a kernel body in
+# VMEM, so a kernel's footprint is its pipelined blocks and scratch plus
+# a number of live window-sized values that grows with the tap count.
+# The coefficients below are fitted (rounded up) to the smallest
+# ``vmem_limit_bytes`` at which each kernel compiles for a TPU v5e,
+# found by bisection at 1024 rows and checked linear in rows up to
+# 8192; tests/test_tpu_compile.py holds the model to within
+# ``VMEM_MODEL_MARGIN`` of the compiler on both sides.
+# ---------------------------------------------------------------------------
+
+VMEM_MODEL_MARGIN = 0.10
+# Mosaic's fixed internal scratch, and the granularity of the limit.
+_VMEM_SLACK = 2 * 2 ** 20
+
+
+def _taps(spec: StencilSpec) -> int:
+    if spec.layout == "star":
+        return 2 * spec.dims * spec.radius + 1
+    return (2 * spec.radius + 1) ** spec.dims
+
+
+def _window_values(spec: StencilSpec, bt: int, n_streams: int) -> float:
+    """Live window-sized values of one fused step (2D: per row panel;
+    3D: per plane)."""
+    if spec.dims == 2:
+        return (2.5 + _taps(spec) / 2 + (bt > 1)
+                + 2 * (spec.boundary == "clamp") + (n_streams - 1))
+    return (2 * spec.radius + 2) * _taps(spec) / (6 * spec.radius + 1) \
+        + 2 * (n_streams - 1)
+
+
+def kernel_vmem_bytes(spec: StencilSpec, *, rows: int, bx: int, bt: int,
+                      halo: int, n_streams: int, variant: str,
+                      itemsize: int = 4) -> int:
+    """VMEM one in-core engine kernel allocates.
+
+    ``rows`` is the full-height row panel (2D) or plane height (3D),
+    ``halo`` the fused halo, ``n_streams`` the streamed operands (grid
+    + aux streams). Counted: every BlockSpec block double-buffered (three
+    neighbour blocks per stream for the multioperand and 3D kernels, one
+    for revolving) and the output block; the revolving ``3*bx`` scratch
+    per stream; the 3D stage windows and source ring; and the live
+    window values (``_window_values``), all lane-padded.
+    """
+    col = round_up(rows, _SUBLANE[itemsize]) * itemsize   # bytes per lane
+    win = round_up(bx + 2 * halo, _LANE)
+    values = _window_values(spec, bt, n_streams) * win
+    if spec.dims == 2:
+        if variant == "revolving":
+            blocks = n_streams * (2 * bx + 3 * bx)
+        else:
+            blocks = n_streams * 3 * 2 * bx
+        return int(col * (blocks + 2 * bx + values))
+    stages = bt * (2 * spec.radius + 1) * win
+    ring = (halo + 1) * win * (n_streams > 1)
+    blocks = n_streams * 3 * 2 * bx + 2 * bx
+    return int(col * (blocks + stages + ring + values))
+
+
+def persistent_vmem_bytes(spec: StencilSpec, slab_shape: Tuple[int, ...],
+                          *, bx: int, bt: int, tile: int,
+                          itemsize: int = 4) -> int:
+    """VMEM of the persistent out-of-core kernel
+    (``engine.stencil_call_persistent``): two DMA slabs of ``tile +
+    2*ghost`` leading rows and one result slab, each ``slab_shape`` (the
+    non-leading, lane-padded dims), plus one x tile's window values (and
+    the 3D stage windows)."""
+    g = spec.halo(bt)
+    align = _SUBLANE[itemsize] if spec.dims == 2 else 1
+    rows = round_up(tile, align) + 2 * round_up(g, align)
+    per_row = itemsize
+    for s in slab_shape[:-1]:
+        per_row *= s
+    wp = round_up(slab_shape[-1], bx)
+    slabs = 3 * rows * per_row * wp
+    panel = rows if spec.dims == 2 else slab_shape[0]
+    tile_vmem = kernel_vmem_bytes(spec, rows=panel, bx=bx, bt=bt, halo=g,
+                                  n_streams=1, variant="revolving",
+                                  itemsize=itemsize)
+    return slabs + tile_vmem
+
+
+def persistent_tile(spec: StencilSpec, slab_shape: Tuple[int, ...], *,
+                    bx: int, bt: int, vmem_budget: int, limit: int,
+                    itemsize: int = 4) -> int:
+    """The largest in-kernel tile (<= ``limit`` leading rows) whose
+    persistent-kernel VMEM fits ``vmem_budget``; raises when not even
+    one row fits."""
+    def fits(t):
+        return persistent_vmem_bytes(spec, slab_shape, bx=bx, bt=bt,
+                                     tile=t, itemsize=itemsize) <= vmem_budget
+    if not fits(1):
+        raise ValueError(
+            f"no persistent-kernel tile of a {slab_shape} slab (bx={bx}, "
+            f"bt={bt}) fits the {vmem_budget}-byte VMEM budget")
+    lo, hi = 1, limit
+    while lo < hi:
+        mid = (lo + hi + 1) // 2
+        if fits(mid):
+            lo = mid
+        else:
+            hi = mid - 1
+    return lo
+
+
+def vmem_limit(model_bytes: int) -> int:
+    """The scoped-VMEM limit a kernel asks Mosaic for: its modeled
+    footprint plus slack, in whole MiB."""
+    return round_up(int(model_bytes) + _VMEM_SLACK, 2 ** 20)
 
 
 def incore_resident_bytes(spec: StencilSpec, grid_shape: Tuple[int, ...],
@@ -394,9 +500,11 @@ def plan_tiles(spec: StencilSpec, grid_shape: Tuple[int, ...], *,
 
 
 def candidate_plans(spec: StencilSpec, grid_shape: Tuple[int, ...],
-                    vmem_budget: int = 96 * 2 ** 20,
+                    vmem_budget: int,
                     itemsize: int = 4) -> list[BlockPlan]:
-    """Enumerate legal (bx, bt) configurations under the VMEM budget.
+    """Enumerate legal (bx, bt) configurations under the VMEM budget
+    (a device's ``TpuSpec.vmem_bytes``), by the modeled footprint of
+    the revolving (smaller) kernel variant.
 
     This is the search space the thesis's §5.4 model prunes so only a
     handful of configurations ever reach the (hours-long) place-and-route

@@ -18,6 +18,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterable, Sequence
 
+import jax
+
 from repro.core.blocking import (BlockPlan, TilePlan, candidate_plans,
                                  incore_resident_bytes, shard_extent)
 from repro.core.stencil import StencilSpec
@@ -34,7 +36,7 @@ class TpuSpec:
     hbm_bw: float = 819e9                # bytes/s
     ici_bw: float = 50e9                 # bytes/s per link
     ici_links: int = 4                   # 2D torus: 4 links/chip
-    vmem_bytes: int = 96 * 2 ** 20
+    vmem_capacity: int = 128 * 2 ** 20   # physical VMEM per core
     hbm_bytes: int = 16 * 2 ** 30
     tdp_watts: float = 170.0             # modeled only (DESIGN.md §8)
     # Host-side cost of launching one kernel (dispatch + queueing).
@@ -48,14 +50,22 @@ class TpuSpec:
     # external-DRAM channel, one memory level further out than HBM.
     host_bw: float = 16e9
 
+    @property
+    def vmem_bytes(self) -> int:
+        """The VMEM budget block plans are sized against: capacity
+        less the headroom Mosaic keeps for its own scratch."""
+        return self.vmem_capacity - VMEM_HEADROOM
 
+
+VMEM_HEADROOM = 8 * 2 ** 20
 V5E = TpuSpec()
 # A "next generation" part for the thesis's Stratix 10 projection analog
 # (§5.7.3): ~2.3x compute, ~3.3x HBM of v5e — v5p-class constants.
 V5P_PROJECTION = TpuSpec(name="tpu-v5p-projection",
                          peak_flops_bf16=459e12, peak_flops_f32=229.5e12,
                          vpu_flops_f32=9.2e12, hbm_bw=2765e9, ici_bw=100e9,
-                         vmem_bytes=128 * 2 ** 20, hbm_bytes=95 * 2 ** 30,
+                         vmem_capacity=128 * 2 ** 20,
+                         hbm_bytes=95 * 2 ** 30,
                          tdp_watts=350.0)
 
 # ---------------------------------------------------------------------------
@@ -69,33 +79,39 @@ V5P_PROJECTION = TpuSpec(name="tpu-v5p-projection",
 
 # Server-class x86 host: the interpret/reference backends' device. The
 # compute/bandwidth ratios are what matter to the model prior (AVX-class
-# vector FLOPs vs DDR bandwidth); vmem_bytes models the L2/L3 working
-# set a blocked tile should stay inside, and hbm_bytes deliberately
-# matches V5E's 16 GiB so the *default* in-core/out-of-core routing
-# threshold (outofcore.route_decision) is one number everywhere.
+# vector FLOPs vs DDR bandwidth); the VMEM budget and hbm_bytes
+# deliberately match V5E's so a plan picked here is one the chip
+# accepts and the *default* in-core/out-of-core routing threshold
+# (outofcore.route_decision) is one number everywhere.
 CPU_HOST = TpuSpec(name="cpu-host",
                    peak_flops_bf16=2e12, peak_flops_f32=1e12,
                    vpu_flops_f32=0.5e12, hbm_bw=100e9,
                    ici_bw=25e9, ici_links=1,
-                   vmem_bytes=96 * 2 ** 20, hbm_bytes=16 * 2 ** 30,
+                   hbm_bytes=16 * 2 ** 30,
                    tdp_watts=250.0, dispatch_overhead_s=20e-6,
                    host_bw=100e9)   # "host streaming" is a memcpy here
 
 # A100-class part for the Pallas/Triton GPU lowering (where present).
 # Stencils are CUDA-core (not tensor-core) work, mirroring the VPU
-# reasoning on TPU; vmem_bytes models the L2 + SMEM budget a block
-# plan should fit.
+# reasoning on TPU; the VMEM capacity models the L2 + SMEM budget a
+# block plan should fit.
 GPU_GENERIC = TpuSpec(name="gpu-a100-class",
                       peak_flops_bf16=312e12, peak_flops_f32=19.5e12,
                       vpu_flops_f32=19.5e12, hbm_bw=1555e9,
                       ici_bw=300e9, ici_links=1,
-                      vmem_bytes=40 * 2 ** 20, hbm_bytes=40 * 2 ** 30,
+                      vmem_capacity=48 * 2 ** 20,
+                      hbm_bytes=40 * 2 ** 30,
                       tdp_watts=400.0, dispatch_overhead_s=8e-6,
                       host_bw=25e9)
 
-# Engine-backend name (kernels/ops.py dispatch) -> device spec.
+# ``jax.devices()[0].device_kind`` -> spec of the TPUs the ``pallas``
+# backend can run on (VMEM per core from the Pallas TPU docs).
+TPU_SPECS = {
+    "TPU v5 lite": V5E,
+}
+
+# The other engine backends (kernels/ops.py dispatch) -> device spec.
 DEVICE_SPECS = {
-    "pallas": V5E,
     "interpret": CPU_HOST,
     "reference": CPU_HOST,
     "gpu": GPU_GENERIC,
@@ -105,11 +121,22 @@ DEVICE_SPECS = {
 def device_spec_for(backend: str) -> TpuSpec:
     """The device spec a resolved engine backend runs against.
 
-    Unknown backends fall back to V5E (the historical default) rather
-    than raising — the model prior degrades gracefully; the cache key
-    still records whichever spec name was actually used.
+    ``pallas`` looks the chip up by ``jax.devices()[0].device_kind`` in
+    ``TPU_SPECS``. An unknown kind or backend raises: planning against
+    another chip's VMEM and bandwidth would pick plans the compiler
+    refuses or rank them wrongly.
     """
-    return DEVICE_SPECS.get(backend, V5E)
+    if backend == "pallas":
+        kind = jax.devices()[0].device_kind
+        if kind not in TPU_SPECS:
+            raise ValueError(
+                f"no device spec for TPU kind {kind!r}; known kinds: "
+                f"{sorted(TPU_SPECS)} (add one to perf_model.TPU_SPECS)")
+        return TPU_SPECS[kind]
+    if backend not in DEVICE_SPECS:
+        raise ValueError(f"no device spec for backend {backend!r}; known: "
+                         f"{['pallas'] + sorted(DEVICE_SPECS)}")
+    return DEVICE_SPECS[backend]
 
 
 @dataclasses.dataclass(frozen=True)

@@ -61,18 +61,27 @@ def shift(x: jax.Array, axis: int, offset: int,
     """x shifted so out[i] = x[i + offset] along ``axis``.
 
     Out-of-range reads follow ``boundary``: zero-filled for
-    ``dirichlet0``, edge-replicated for ``clamp``.
+    ``dirichlet0``, edge-replicated for ``clamp``. The edge replicate is
+    a select of the broadcast edge slice over the zero-filled shift
+    (Mosaic lowers no edge-mode pad), so both modes lower inside a
+    TPU kernel.
     """
     if offset == 0:
         return x
     r = abs(offset)
     pad = [(0, 0)] * x.ndim
     pad[axis] = (r, r)
-    mode = "edge" if boundary == "clamp" else "constant"
-    padded = jnp.pad(x, pad, mode=mode)
     idx = [slice(None)] * x.ndim
     idx[axis] = slice(r + offset, r + offset + x.shape[axis])
-    return padded[tuple(idx)]
+    out = jnp.pad(x, pad)[tuple(idx)]
+    if boundary != "clamp":
+        return out
+    n = x.shape[axis]
+    edge = jax.lax.slice_in_dim(x, n - 1 if offset > 0 else 0,
+                                n if offset > 0 else 1, axis=axis)
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    outside = pos >= n - offset if offset > 0 else pos < -offset
+    return jnp.where(outside, edge, out)
 
 
 def shift_nd(x: jax.Array, offsets, boundary: str = "dirichlet0") -> jax.Array:

@@ -76,7 +76,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import compat
 from repro.core.blocking import shard_extent
@@ -205,7 +205,7 @@ def gather_slab(slabs, bounds, start: int, end: int, *, ax: int = 0,
     return np.concatenate(pieces, axis=ax), foreign
 
 
-def _engine_call(slab, specs, bx, bts, variant, interpret, extras, scals,
+def _engine_call(slab, specs, bx, bts, variant, backend, extras, scals,
                  lo, hi):
     """Run the single-device engine on one slab.
 
@@ -217,12 +217,12 @@ def _engine_call(slab, specs, bx, bts, variant, interpret, extras, scals,
     extras = dict(extras)
     src = extras.pop(_LEGACY_SRC, None)
     return engine.stencil_call_program(
-        slab, specs, bx=bx, bt=bts, variant=variant, interpret=interpret,
+        slab, specs, bx=bx, bt=bts, variant=variant, backend=backend,
         source=src, aux=extras or None, scalars=scals,
         valid_lo=lo, valid_hi=hi)
 
 
-def _sweep(xs, specs, *, bx, bts, variant, interpret, idx, n, S, extent,
+def _sweep(xs, specs, *, bx, bts, variant, backend, idx, n, S, extent,
            overlap, axis_name, extras, scals, ax=0, halos=None,
            send_depth=None):
     """One blocked sweep (``bts`` fused steps of the ``specs`` group)
@@ -271,7 +271,7 @@ def _sweep(xs, specs, *, bx, bts, variant, interpret, idx, n, S, extent,
         slab = jnp.concatenate([fa, xs, fb], axis=ax)
         lo = jnp.clip(h - row0, 0, S + 2 * h)
         hi = jnp.clip(extent - row0 + h, 0, S + 2 * h)
-        out = _engine_call(slab, specs, bx, bts, variant, interpret,
+        out = _engine_call(slab, specs, bx, bts, variant, backend,
                            slabs(0, S + 2 * h), scals, lo, hi)
         if send_depth is None:
             return _sl(out, h, h + S, ax)
@@ -288,7 +288,7 @@ def _sweep(xs, specs, *, bx, bts, variant, interpret, idx, n, S, extent,
     if S > 2 * h:      # interior rows [h, S-h) need no halo at all
         hi_own = jnp.clip(extent - row0, 0, S)
         interior = [_sl(_engine_call(
-            xs, specs, bx, bts, variant, interpret,
+            xs, specs, bx, bts, variant, backend,
             {name: es for name, _, _, es in extras},
             scals, 0, hi_own), h, S - h, ax)]
     else:              # S == 2h: the two edge strips cover the shard
@@ -299,12 +299,12 @@ def _sweep(xs, specs, *, bx, bts, variant, interpret, idx, n, S, extent,
                             axis=ax)                      # rows [S-2h, S+h)
     lo_t = jnp.clip(h - row0, 0, 3 * h)
     hi_t = jnp.clip(extent - row0 + h, 0, 3 * h)
-    top_out = _engine_call(tslab, specs, bx, bts, variant, interpret,
+    top_out = _engine_call(tslab, specs, bx, bts, variant, backend,
                            slabs(0, 3 * h), scals, lo_t, hi_t)
     top = _sl(top_out, h, 2 * h, ax)
     lo_b = jnp.clip(2 * h - row0 - S, 0, 3 * h)
     hi_b = jnp.clip(extent - row0 - S + 2 * h, 0, 3 * h)
-    bot_out = _engine_call(bslab, specs, bx, bts, variant, interpret,
+    bot_out = _engine_call(bslab, specs, bx, bts, variant, backend,
                            slabs(S - h, S + 2 * h), scals, lo_b, hi_b)
     bot = _sl(bot_out, h, 2 * h, ax)
     out = jnp.concatenate([top] + interior + [bot], axis=ax)
@@ -322,7 +322,7 @@ def _sweep(xs, specs, *, bx, bts, variant, interpret, idx, n, S, extent,
 
 def stencil_run_sharded(x: jax.Array, spec: StencilSpec, n_steps: int, *,
                         n_devices: int, bx: int = 256, bt: int = 1,
-                        variant: str = "revolving", interpret: bool = True,
+                        variant: str = "revolving", backend: str,
                         source: jax.Array | None = None, aux=None,
                         scalars: jax.Array | None = None, devices=None,
                         overlap: bool = True,
@@ -426,7 +426,7 @@ def stencil_run_sharded(x: jax.Array, spec: StencilSpec, n_steps: int, *,
     mesh = _device_mesh(n, devices)
     runner = _sharded_runner(
         spec, mesh, key=(spec, xp.shape, str(xp.dtype), bx,
-                         tuple(schedule), variant, interpret, n, S,
+                         tuple(schedule), variant, backend, n, S,
                          extent, overlap, axis_name, extra_names,
                          scalars is not None,
                          None if scalars is None else scalars.shape,
@@ -434,7 +434,7 @@ def stencil_run_sharded(x: jax.Array, spec: StencilSpec, n_steps: int, *,
                          tuple(int(d.id) for d in np.asarray(
                              mesh.devices).flat)),
         h_max=h_max, schedule=schedule, bx=bx, variant=variant,
-        interpret=interpret, n=n, S=S, extent=extent, overlap=overlap,
+        backend=backend, n=n, S=S, extent=extent, overlap=overlap,
         axis_name=axis_name, extra_names=extra_names,
         has_scalars=scalars is not None,
         per_problem_scal=per_problem_scal, strategy=strategy, ga=ga)
@@ -444,6 +444,17 @@ def stencil_run_sharded(x: jax.Array, spec: StencilSpec, n_steps: int, *,
     return _sl(out, None, extent, ga)
 
 
+def _placed(fn, mesh: Mesh, in_specs):
+    """``fn`` with every argument first placed on ``mesh`` as its
+    shard_map ``in_specs`` say: jit does not move an array that is
+    committed to one device onto the mesh by itself."""
+    shardings = tuple(NamedSharding(mesh, s) for s in in_specs)
+
+    def call(*args):
+        return fn(*(jax.device_put(a, s) for a, s in zip(args, shardings)))
+    return call
+
+
 # jitted shard_map programs memoized per static configuration: without
 # this, every call (each autotuner timing repeat, every step block of a
 # caller's loop) would rebuild the closure and retrace from scratch.
@@ -451,7 +462,7 @@ _RUNNERS: dict = {}
 
 
 def _sharded_runner(spec, mesh, *, key, h_max, schedule, bx, variant,
-                    interpret, n, S, extent, overlap, axis_name,
+                    backend, n, S, extent, overlap, axis_name,
                     extra_names, has_scalars, per_problem_scal=False,
                     strategy="grid", ga=0):
     fn = _RUNNERS.get(key)
@@ -473,7 +484,7 @@ def _sharded_runner(spec, mesh, *, key, h_max, schedule, bx, variant,
             off = 0
             for bts in schedule:
                 xs = _engine_call(
-                    xs, (spec,), bx, bts, variant, interpret, extras_d,
+                    xs, (spec,), bx, bts, variant, backend, extras_d,
                     (_tsl(scal, off, off + bts),) if scal is not None
                     else None, None, None)
                 off += bts
@@ -506,7 +517,7 @@ def _sharded_runner(spec, mesh, *, key, h_max, schedule, bx, variant,
                 h_next = hs[t + 1] if t + 1 < len(schedule) else 0
                 xs, (st, sb) = _sweep(
                     xs, (spec,), bx=bx, bts=bts, variant=variant,
-                    interpret=interpret, idx=idx, n=n, S=S,
+                    backend=backend, idx=idx, n=n, S=S,
                     extent=extent, overlap=overlap,
                     axis_name=axis_name, extras=extras,
                     scals=((_tsl(scal, off, off + bts),)
@@ -525,9 +536,9 @@ def _sharded_runner(spec, mesh, *, key, h_max, schedule, bx, variant,
             in_specs += (P(),)
         out_spec = shard_p
 
-    fn = jax.jit(compat.shard_map(
+    fn = _placed(jax.jit(compat.shard_map(
         body, mesh=mesh, in_specs=in_specs,
-        out_specs=out_spec, check_vma=False))
+        out_specs=out_spec, check_vma=False)), mesh, in_specs)
     _RUNNERS[key] = fn
     return fn
 
@@ -543,7 +554,7 @@ def _sharded_runner(spec, mesh, *, key, h_max, schedule, bx, variant,
 def stencil_program_run_sharded(fields: dict, program, n_steps: int, *,
                                 n_devices: int, bx: int = 256, bt: int = 1,
                                 variant: str = "revolving",
-                                interpret: bool = True, inputs=None,
+                                backend: str, inputs=None,
                                 scalars=None, devices=None,
                                 overlap: bool = True, fuse: bool = True,
                                 axis_name: str = AXIS) -> dict:
@@ -682,12 +693,12 @@ def stencil_program_run_sharded(fields: dict, program, n_steps: int, *,
 
     mesh = _device_mesh(n, devices)
     key = ("program", program, tuple(a.shape for a in args),
-           str(dt), bx, schedule, variant, interpret, n, S, extent,
+           str(dt), bx, schedule, variant, backend, n, S, extent,
            overlap, axis_name, fuse, strategy, ga, tuple(per_scal),
            tuple(int(d.id) for d in np.asarray(mesh.devices).flat))
     runner = _program_sharded_runner(
         program, mesh, key=key, group_meta=group_meta, h_max=h_max,
-        schedule=schedule, bx=bx, variant=variant, interpret=interpret,
+        schedule=schedule, bx=bx, variant=variant, backend=backend,
         n=n, S=S, extent=extent, overlap=overlap, axis_name=axis_name,
         field_names=field_names, input_names=input_names,
         scal_names=scal_names, per_scal=tuple(per_scal),
@@ -699,7 +710,7 @@ def stencil_program_run_sharded(fields: dict, program, n_steps: int, *,
 
 
 def _program_sharded_runner(program, mesh, *, key, group_meta, h_max,
-                            schedule, bx, variant, interpret, n, S,
+                            schedule, bx, variant, backend, n, S,
                             extent, overlap, axis_name, field_names,
                             input_names, scal_names, per_scal, strategy,
                             ga=0):
@@ -728,7 +739,7 @@ def _program_sharded_runner(program, mesh, *, key, group_meta, h_max,
                     extras = {nm: (fs[nm] if nm in fs else ins[nm])
                               for nm in aux_names}
                     fs[fld] = _engine_call(
-                        fs[fld], specs, bx, bts, variant, interpret,
+                        fs[fld], specs, bx, bts, variant, backend,
                         extras, group_scals(scal_d, scal_keys, off, bts),
                         None, None)
                 off += bts
@@ -766,7 +777,7 @@ def _program_sharded_runner(program, mesh, *, key, group_meta, h_max,
                             extras.append((nm,) + ins_ex[nm])
                     fs[fld] = _sweep(
                         fs[fld], specs, bx=bx, bts=bts, variant=variant,
-                        interpret=interpret, idx=idx, n=n, S=S,
+                        backend=backend, idx=idx, n=n, S=S,
                         extent=extent, overlap=overlap,
                         axis_name=axis_name, extras=extras,
                         scals=group_scals(scal_d, scal_keys, off, bts),
@@ -782,8 +793,8 @@ def _program_sharded_runner(program, mesh, *, key, group_meta, h_max,
         in_specs += (P(),) * len(scal_names)
         out_specs = (shard_p,) * nf
 
-    fn = jax.jit(compat.shard_map(
+    fn = _placed(jax.jit(compat.shard_map(
         body, mesh=mesh, in_specs=in_specs,
-        out_specs=out_specs, check_vma=False))
+        out_specs=out_specs, check_vma=False)), mesh, in_specs)
     _RUNNERS[key] = fn
     return fn

@@ -13,7 +13,7 @@ This is the thesis's §5.4 tuning flow made a first-class subsystem:
      ``(spec, shape, dtype, backend, vmem_budget, tpu, n_devices)`` so
      the search runs once per problem class per machine
      (``REPRO_AUTOTUNE_CACHE`` overrides the location; default
-     ``~/.cache/repro/autotune.json``). Model-prior choices are never
+     ``<checkout>/.cache/autotune.json``). Model-prior choices are never
      persisted: they are cheap to recompute and must not shadow a later
      forced measurement.
 
@@ -102,6 +102,10 @@ class TunedPlan:
     # (``plan_tiles`` picks the largest fit), so this is provenance
     # plus a cache round-trip, not a second source of truth.
     tile: Optional[int] = None
+    # (bx, bt, variant) -> why that measured candidate could not run
+    # (e.g. the compiler refused its VMEM). Empty unless measured.
+    failures: Dict[Tuple[int, int, str], str] = dataclasses.field(
+        default_factory=dict, compare=False)
 
 
 # ---------------------------------------------------------------------------
@@ -112,7 +116,8 @@ def cache_path() -> pathlib.Path:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return pathlib.Path(env)
-    return pathlib.Path.home() / ".cache" / "repro" / "autotune.json"
+    from repro.compile_cache import CHECKOUT
+    return CHECKOUT / ".cache" / "autotune.json"
 
 
 # Parsed cache files memoized per path so resolving a plan in a loop
@@ -252,15 +257,18 @@ def _measure(x, spec, plans, variants, backend, timer,
              hbm_budget: int | None = None, extra_streams: int = 0,
              program=None, pipeline: str = "host"):
     """Time each (plan, variant); return (winner, winner_variant,
-    {(bx, bt): best seconds-per-step}). With ``n_devices > 1`` each
-    candidate is one sweep of the sharded deep-halo runner (collective
-    cost included); with an ``hbm_budget`` the run auto-routes through
-    the out-of-core runner, so tile streaming cost is *in* the
-    measurement; candidates that cannot run — e.g. too few visible
-    devices — just leave the race. With a ``program`` each candidate
-    is ``p.bt`` program steps of ``ops.stencil_program_run``."""
+    {(bx, bt): best seconds-per-step}, {(bx, bt, variant): failure}).
+    With ``n_devices > 1`` each candidate is one sweep of the sharded
+    deep-halo runner (collective cost included); with an
+    ``hbm_budget`` the run auto-routes through the out-of-core runner,
+    so tile streaming cost is *in* the measurement. A candidate that
+    cannot run — the compiler refuses it, too few devices are visible —
+    leaves the race with its reason recorded and logged. With a
+    ``program`` each candidate is ``p.bt`` program steps of
+    ``ops.stencil_program_run``."""
     from repro.kernels import ops
     timings: Dict[Tuple[int, int], float] = {}
+    failures: Dict[Tuple[int, int, str], str] = {}
     best = (None, None, float("inf"))
     # Specs that declare operands still race: synthesize zero aux grids
     # and unit scalars of the declared shapes (timing does not care
@@ -297,8 +305,12 @@ def _measure(x, spec, plans, variants, backend, timer,
                     pipeline=pipeline))
             try:
                 run()  # warm-up / compile
-            except Exception:   # noqa: BLE001 - an illegal candidate
-                continue        # just leaves the race
+            except Exception as e:   # noqa: BLE001 - recorded, not hidden
+                failures[(p.bx, p.bt, v)] = f"{type(e).__name__}: {e}"
+                _LOG.warning("autotune candidate bx=%d bt=%d %s failed: "
+                             "%s", p.bx, p.bt, v,
+                             failures[(p.bx, p.bt, v)])
+                continue
             dt = float("inf")
             for _ in range(repeats):
                 t0 = timer()
@@ -309,7 +321,7 @@ def _measure(x, spec, plans, variants, backend, timer,
             timings[key] = min(timings.get(key, float("inf")), per_step)
             if per_step < best[2]:
                 best = (p, v, per_step)
-    return best[0], best[1], timings
+    return best[0], best[1], timings, failures
 
 
 def plan(shape, spec, *, dtype="float32",
@@ -507,17 +519,20 @@ def plan(shape, spec, *, dtype="float32",
         # The *effective* budget (tpu default applied), not the raw
         # argument: measurement must route the same in-core/out-of-core
         # path the ranking priced, even for a non-default TpuSpec.
-        winner, w_variant, timings = _measure(
+        winner, w_variant, timings, failures = _measure(
             x, spec, shortlist, variants, backend, timer,
             n_devices=n_devices, hbm_budget=hbm,
             extra_streams=extra_streams, program=program,
             pipeline=pipeline)
-        if winner is not None:
-            tuned = _mk(winner.bx, winner.bt, w_variant, "measured",
-                        timings, tile=_tile_of(winner))
-        else:   # every candidate failed to run; fall back to the prior
-            tuned = _mk(shortlist[0].bx, shortlist[0].bt, variants[0],
-                        "model", tile=_tile_of(shortlist[0]))
+        if winner is None:
+            raise RuntimeError(
+                f"autotune: every candidate for {spec.name!r} on grid "
+                f"{shape} ({backend}) failed to run:\n" + "\n".join(
+                    f"  bx={bx} bt={bt} {v}: {why}"
+                    for (bx, bt, v), why in failures.items()))
+        tuned = dataclasses.replace(
+            _mk(winner.bx, winner.bt, w_variant, "measured", timings,
+                tile=_tile_of(winner)), failures=failures)
     else:
         tuned = _mk(shortlist[0].bx, shortlist[0].bt, variants[0],
                     "model", tile=_tile_of(shortlist[0]))

@@ -78,7 +78,9 @@ import jax.numpy as jnp
 
 from repro import compat
 from repro.compat import pl, pltpu
-from repro.core.blocking import _SUBLANE, BlockPlan, round_up
+from repro.core.blocking import (_SUBLANE, BlockPlan, kernel_vmem_bytes,
+                                 persistent_vmem_bytes, round_up,
+                                 vmem_limit)
 from repro.core.stencil import StencilSpec
 
 VARIANTS_2D = ("revolving", "multioperand")
@@ -101,16 +103,18 @@ def variants_for(dims: int, backend: str | None = None) -> tuple[str, ...]:
     return VARIANTS_2D if dims == 2 else VARIANTS_3D
 
 
-def _resolve_engine_backend(backend: str | None, interpret: bool) -> str:
-    """Backward-compatible backend resolution: callers that predate the
-    multi-backend engine pass only ``interpret``."""
-    if backend is None:
-        return "interpret" if interpret else "pallas"
-    if backend not in ("interpret", "pallas", "gpu"):
+ENGINE_BACKENDS = ("interpret", "pallas", "gpu")
+
+
+def check_backend(backend: str) -> str:
+    """Validate a *resolved* engine backend. There is no default: a
+    caller that names none would otherwise run the interpreter on the
+    chip without saying so."""
+    if backend not in ENGINE_BACKENDS:
         raise ValueError(
             f"unknown engine backend {backend!r}; expected one of "
-            f"('interpret', 'pallas', 'gpu') — 'reference' and 'auto' "
-            f"resolve in kernels.ops, not here")
+            f"{ENGINE_BACKENDS} — 'reference' and 'auto' resolve in "
+            f"kernels.ops, not here")
     return backend
 
 
@@ -137,22 +141,36 @@ def boundary_fill(win, boundary: str, tile_idx, bx: int, halo: int,
     """Re-impose the true-grid boundary on a [rows, width] window.
 
     ``dirichlet0``: out-of-grid cells read 0. ``clamp``: out-of-grid
-    cells read the nearest in-grid cell (edge replicate) — implemented
-    as a row/column re-index with indices clipped into the valid
-    interval, so it works with traced ``row_lo``/``row_hi`` (sharded
-    slabs clamp at *global* grid edges only, never at shard edges).
+    cells read the nearest in-grid cell (edge replicate). Mosaic lowers
+    no gather of this shape, so clamp is built from selects: the edge
+    columns sit at static window offsets (column 0 only ever falls in
+    tile 0's window; column ``true_w - 1`` in at most the two last
+    tiles'), and the edge rows of the traced interval ``[row_lo,
+    row_hi)`` are picked out by a masked sum over rows, which is exact
+    (one term is the row, the rest are zeros). Sharded slabs therefore
+    clamp at *global* grid edges only, never at shard edges.
     """
     rows, width = win.shape
-    if boundary == "clamp":
-        col0 = tile_idx * bx - halo
-        ri = jnp.clip(jnp.arange(rows, dtype=jnp.int32), row_lo,
-                      jnp.maximum(row_hi - 1, row_lo))
-        ci = jnp.clip(jnp.arange(width, dtype=jnp.int32) + col0,
-                      0, true_w - 1) - col0
-        return jnp.take(jnp.take(win, ri, axis=0, mode="clip"),
-                        ci, axis=1, mode="clip")
-    mask = window_mask(tile_idx, bx, halo, rows, true_w, row_lo, row_hi)
-    return jnp.where(mask, win, jnp.zeros_like(win))
+    if boundary != "clamp":
+        mask = window_mask(tile_idx, bx, halo, rows, true_w, row_lo,
+                           row_hi)
+        return jnp.where(mask, win, jnp.zeros_like(win))
+    col0 = tile_idx * bx - halo
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    left = win[:, halo: halo + 1]
+    right = left
+    for t in range(-(-true_w // bx)):
+        e = true_w - 1 - (t * bx - halo)     # column true_w-1 in tile t
+        if 0 <= e < width - 1:               # tile t's window crosses it
+            right = jnp.where(tile_idx == t, win[:, e: e + 1], right)
+    win = jnp.where(cols < 0, left, jnp.where(cols >= true_w, right, win))
+    rr = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    last = jnp.maximum(row_hi - 1, row_lo)
+    zero = jnp.zeros_like(win)
+    top = jnp.sum(jnp.where(rr == row_lo, win, zero), axis=0,
+                  keepdims=True)
+    bot = jnp.sum(jnp.where(rr == last, win, zero), axis=0, keepdims=True)
+    return jnp.where(rr < row_lo, top, jnp.where(rr > last, bot, win))
 
 
 def fused_steps(win, specs, bt: int, apply_fns, fills,
@@ -451,6 +469,16 @@ def _limits(lo, hi, true_n: int) -> jax.Array:
                       jnp.asarray(hi, jnp.int32)]).reshape(1, 2)
 
 
+def _vmem_limit(specs, rows, bx, bt, halo, n_streams, variant, dtype):
+    """Scoped-VMEM limit of one kernel launch: the modeled footprint
+    (``core.blocking.kernel_vmem_bytes``) of its costliest fused stage."""
+    return vmem_limit(max(
+        kernel_vmem_bytes(sp, rows=rows, bx=bx, bt=bt, halo=halo,
+                          n_streams=n_streams, variant=variant,
+                          itemsize=jnp.dtype(dtype).itemsize)
+        for sp in specs))
+
+
 def _run_2d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
             coeffss, scalarss, apply_fns, valid_lo, valid_hi):
     interpret = backend == "interpret"
@@ -493,7 +521,6 @@ def _run_2d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
             head_specs.append(pl.BlockSpec(scal.shape,
                                            lambda *_: (0, 0)))
         head_args.append(scal)
-    params = compat.compiler_params_for(backend, 2 if batched else 1)
     kern_kw = dict(specs=specs, bx=bx, bt=bt, halo=halo, true_w=true_w,
                    stages=stages, apply_fns=apply_fns, batched=batched)
     streamed = [xp]
@@ -503,6 +530,9 @@ def _run_2d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
         streamed += [jnp.pad(c.astype(x.dtype), pad2) for c in cps]
     n_streamed = len(streamed)
     grid = ((x.shape[0],) if batched else ()) + (nt,)
+    params = compat.compiler_params_for(
+        backend, len(grid), _vmem_limit(specs, rows, bx, bt, halo,
+                                        n_streamed, variant, x.dtype))
 
     if variant == "multioperand":
         kern = functools.partial(_kernel_2d_multi, **kern_kw)
@@ -598,7 +628,10 @@ def _run_3d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
             jnp.maximum(k - fill, 0), 0, i))),
         out_shape=jax.ShapeDtypeStruct(xp.shape, xp.dtype),
         scratch_shapes=scratch,
-        compiler_params=compat.compiler_params_for(backend, len(grid)),
+        compiler_params=compat.compiler_params_for(
+            backend, len(grid),
+            _vmem_limit(specs, rows, bx, bt, fill, 1 + has_src,
+                        variant, x.dtype)),
         interpret=interpret,
     )(*((lim, xp, xp, xp, sp, sp, sp) if has_src else (lim, xp, xp, xp)))
     return out[..., :true_d, :true_h, :true_w]
@@ -654,8 +687,8 @@ def _probe_kernel_dma() -> tuple:
         out = pl.pallas_call(
             kern,
             grid=(1,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             out_shape=jax.ShapeDtypeStruct((4, 128), jnp.float32),
             scratch_shapes=[pltpu.VMEM((4, 128), jnp.float32),
                             pltpu.SemaphoreType.DMA,
@@ -718,31 +751,31 @@ def kernel_pipeline_supported(spec: StencilSpec, *, backend: str,
     return True, ""
 
 
-def _slab_compute_2d(buf, row_lo, row_hi, *, spec, bx, bt, true_w,
-                     apply_fn):
-    """One fused block over a resident (rows, nt*bx) 2D slab.
+def _col_tile(ref, t, bx: int):
+    """Columns ``[t*bx, (t+1)*bx)`` of a ``[..., rows, cols]`` VMEM ref
+    at a traced tile index (a lane-aligned dynamic slice)."""
+    return ref[..., pl.ds(pl.multiple_of(t * bx, bx), bx)]
 
-    Structured to trace exactly like the interpret lowering of the
-    multioperand kernel's grid — rows padded to the sublane tile, a
-    ``fori_loop`` over x tiles with a *traced* tile index,
-    ``dynamic_slice`` block reads (interpret mode scans the grid as one
-    loop), and *traced* row limits (the in-core kernel reads them from
-    the loop-carried ``lim`` operand) — so XLA makes the same fusion
-    (hence fma-contraction) decisions and the values stay bitwise equal
-    to the in-core engine, not just 1-ulp close.
+
+def _slab_compute_2d(src, dst, row_lo, row_hi, *, spec, bx, bt, true_w,
+                     apply_fn):
+    """One fused block over a VMEM-resident ``(rows, nt*bx)`` 2D slab
+    ref ``src``, written to the same-shape ref ``dst``.
+
+    Per x tile (a ``fori_loop`` with a traced tile index) the window is
+    assembled from the three neighbouring column tiles exactly as the
+    multioperand kernel assembles it from its three BlockSpec blocks,
+    then filled, stepped and cropped with the in-core expression
+    sequence; the row limits arrive traced, as in-core.
     """
-    rows_in, wp = buf.shape
-    hp = round_up(rows_in, _SUBLANE[buf.dtype.itemsize])
-    buf = jnp.pad(buf, ((0, hp - rows_in), (0, 0)))
-    nt = wp // bx
+    nt = src.shape[-1] // bx
     halo = bt * spec.radius
 
-    def tbody(j, out):
-        starts = (jnp.maximum(j - 1, 0) * bx, j * bx,
-                  jnp.minimum(j + 1, nt - 1) * bx)
+    def tbody(j, carry):
         cat = jnp.concatenate(
-            [jax.lax.dynamic_slice(buf, (0, s), (hp, bx))
-             for s in starts], axis=1)
+            [_col_tile(src, jnp.maximum(j - 1, 0), bx),
+             _col_tile(src, j, bx),
+             _col_tile(src, jnp.minimum(j + 1, nt - 1), bx)], axis=1)
         win = cat[:, bx - halo: 2 * bx + halo]
 
         def fill(w):
@@ -750,139 +783,122 @@ def _slab_compute_2d(buf, row_lo, row_hi, *, spec, bx, bt, true_w,
                                  row_lo, row_hi)
 
         win = fused_steps(win, (spec,), bt, (apply_fn,), [fill])
-        return jax.lax.dynamic_update_slice(
-            out, win[:, halo: halo + bx], (0, j * bx))
+        dst[:, pl.ds(pl.multiple_of(j * bx, bx), bx)] = \
+            win[:, halo: halo + bx]
+        return carry
 
-    out = jax.lax.fori_loop(0, nt, tbody, jnp.zeros((hp, wp), buf.dtype))
-    return out[:rows_in]
+    jax.lax.fori_loop(0, nt, tbody, 0)
 
 
-def _slab_compute_3d(buf, d_lo, d_hi, *, spec, bx, bt, true_w, apply_fn):
-    """One fused block over a resident (d, rows, nt*bx) 3D slab: the
-    z-streaming plane pipeline of ``_kernel_3d_stream``, run as one
-    ``fori_loop`` over the flattened (x tile, z step) grid with the
-    rolling stage windows in the carry — the same per-plane ops the
-    interpret lowering discharges the in-core kernel to (rows padded to
-    the sublane tile, traced tile/z indices and z limits, elementwise
-    ``.at`` roll writes), which keeps the values bitwise equal to the
-    in-core engine."""
-    d, rows_in, wp = buf.shape
-    hp = round_up(rows_in, _SUBLANE[buf.dtype.itemsize])
-    buf = jnp.pad(buf, ((0, 0), (0, hp - rows_in), (0, 0)))
+def _slab_compute_3d(src, dst, win_ref, d_lo, d_hi, *, spec, bx, bt,
+                     true_w, apply_fn):
+    """One fused block over a VMEM-resident ``(d, rows, nt*bx)`` 3D slab
+    ref: the z-streaming plane pipeline of ``_kernel_3d_stream``, run as
+    a ``fori_loop`` over x tiles around a ``fori_loop`` over z steps,
+    with the rolling stage windows in the ``win_ref`` scratch (re-zeroed
+    per x tile, as the in-core kernel re-zeros at ``k == 0``)."""
+    d, rows, wp = src.shape
     nt = wp // bx
     r = spec.radius
     fill_d = bt * r
     clamp = spec.boundary == "clamp"
-    kmax = d + fill_d
 
-    def body(idx, carry):
-        win, out = carry
-        i = idx // kmax
-        k = idx - i * kmax
-        # Fresh pipeline per x tile: the in-core kernel re-zeros its
-        # rolling scratch at k == 0 (pl.when discharges to a select).
-        win = jnp.where(k == 0, jnp.zeros_like(win), win)
+    def zbody(k, i):
         kc = jnp.minimum(k, d - 1)
-        starts = (jnp.maximum(i - 1, 0) * bx, i * bx,
-                  jnp.minimum(i + 1, nt - 1) * bx)
         cat = jnp.concatenate(
-            [jax.lax.dynamic_slice(buf, (kc, 0, s), (1, hp, bx))[0]
-             for s in starts], axis=1)
+            [_col_tile(src.at[kc], jnp.maximum(i - 1, 0), bx),
+             _col_tile(src.at[kc], i, bx),
+             _col_tile(src.at[kc], jnp.minimum(i + 1, nt - 1), bx)], axis=1)
         plane = cat[:, bx - fill_d: 2 * bx + fill_d]
         # In-plane bounds are static (y/x are never streamed), exactly
         # as in _kernel_3d_stream; only the z interval is traced.
-        xymask = window_mask(i, bx, fill_d, hp, true_w, 0, rows_in)
+        xymask = window_mask(i, bx, fill_d, rows, true_w, 0, rows)
         zero = jnp.zeros_like(plane)
         zin = (k >= d_lo) & (k < d_hi)
 
         def fill_xy(p):
-            return boundary_fill(p, spec.boundary, i, bx, fill_d,
-                                 true_w, 0, rows_in)
+            return boundary_fill(p, spec.boundary, i, bx, fill_d, true_w,
+                                 0, rows)
 
         if clamp:
             plane = fill_xy(plane)
         else:
             plane = jnp.where(xymask & zin, plane, zero)
         for s in range(bt):
-            for j2 in range(2 * r):
-                win = win.at[s, j2].set(win[s, j2 + 1])
-            win = win.at[s, 2 * r].set(plane)
+            for j in range(2 * r):
+                win_ref[s, j] = win_ref[s, j + 1]
+            win_ref[s, 2 * r] = plane
             z_out = k - (s + 1) * r
-            stage_win = win[s]
+            stage_win = win_ref[s]
             if clamp:
-                stage_win = _z_clamped_window(stage_win, z_out, d_lo,
-                                              d_hi, r)
+                stage_win = _z_clamped_window(stage_win, z_out, d_lo, d_hi,
+                                              r)
             updated = apply_fn(stage_win, spec, None, None)
             if clamp:
                 plane = fill_xy(updated)
             else:
-                plane = jnp.where(
-                    xymask & (z_out >= d_lo) & (z_out < d_hi),
-                    updated, zero)
-        out = jax.lax.dynamic_update_slice(
-            out, plane[None, :, fill_d: fill_d + bx],
-            (jnp.maximum(k - fill_d, 0), 0, i * bx))
-        return win, out
+                plane = jnp.where(xymask & (z_out >= d_lo) & (z_out < d_hi),
+                                  updated, zero)
+        dst[jnp.maximum(k - fill_d, 0), :,
+            pl.ds(pl.multiple_of(i * bx, bx), bx)] = plane[:, fill_d:
+                                                           fill_d + bx]
+        return i
 
-    win0 = jnp.zeros((bt, 2 * r + 1, hp, bx + 2 * fill_d), buf.dtype)
-    out0 = jnp.zeros((d, hp, wp), buf.dtype)
-    _, out = jax.lax.fori_loop(0, nt * kmax, body, (win0, out0))
-    return out[:, :rows_in]
+    def xbody(i, carry):
+        win_ref[...] = jnp.zeros_like(win_ref)
+        jax.lax.fori_loop(0, d + fill_d, zbody, i)
+        return carry
+
+    jax.lax.fori_loop(0, nt, xbody, 0)
 
 
-def _kernel_persistent(lim_ref, x_hbm, o_hbm, in_buf, out_buf, in_sems,
-                       out_sem, *, compute, tile, g, lead, owned,
-                       chunk_len, dma_len, out_dma, n_inner):
+def _kernel_persistent(x_hbm, o_hbm, in_buf, res_buf, *rest, compute,
+                       tile, ghost, lead, valid, n_rows, dma_len, n_inner,
+                       align):
     """Grid step ``i`` computes tile ``i`` of the chunk; the DMA for
     tile ``i+1``'s slab is started *before* waiting on tile ``i``'s, so
-    it lands under tile ``i``'s fused-step compute. Slot parity is kept
-    static (two ``pl.when`` arms) so reads/waits never index a buffer
-    with a traced slot."""
+    it lands under tile ``i``'s fused-step compute. ``rest`` holds the
+    compute's own scratch (3D stage windows) then the semaphores.
+
+    Every row offset and DMA size is a multiple of ``align`` (2D HBM
+    rows are (8, 128)-tiled): the chunk arrives padded so its first
+    owned row is aligned, tiles and ghosts are whole multiples, and
+    ``valid`` bounds the real chunk rows, which the slab compute reads
+    as traced limits — padding rows are outside the grid, exactly like
+    the host loop's clipped slab edge."""
+    *scratch, in_sems, out_sem = rest
     i = pl.program_id(0)
+    slot = i % 2
 
     def in_off(t):
         # Fixed-size DMA window (pl.ds needs a static size) at a
         # clamped offset: edge tiles widen into real chunk rows, which
         # the crop's dependency cone cannot distinguish from the host
         # loop's clipped slab.
-        return jnp.clip(lead + t * tile - g, 0, chunk_len - dma_len)
+        return pl.multiple_of(
+            jnp.clip(lead + t * tile - ghost, 0, n_rows - dma_len), align)
 
-    def copy_in(t, slot):
+    def copy_in(t, s):
         return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(in_off(t), dma_len)], in_buf.at[slot],
-            in_sems.at[slot])
+            x_hbm.at[pl.ds(in_off(t), dma_len)], in_buf.at[s],
+            in_sems.at[s])
 
     @pl.when(i == 0)
     def _start_first():
         copy_in(0, 0).start()
 
-    @pl.when((i + 1 < n_inner) & ((i + 1) % 2 == 0))
-    def _prefetch_even():
-        copy_in(i + 1, 0).start()
+    @pl.when(i + 1 < n_inner)
+    def _prefetch():
+        copy_in(i + 1, 1 - slot).start()
 
-    @pl.when((i + 1 < n_inner) & ((i + 1) % 2 == 1))
-    def _prefetch_odd():
-        copy_in(i + 1, 1).start()
-
-    @pl.when(i % 2 == 0)
-    def _wait_even():
-        copy_in(i, 0).wait()
-
-    @pl.when(i % 2 == 1)
-    def _wait_odd():
-        copy_in(i, 1).wait()
-
-    # The inactive slot may be mid-DMA; its values are select-discarded.
-    buf = jnp.where(i % 2 == 0, in_buf[0], in_buf[1])
-    res = compute(buf, lim_ref[0, 0], lim_ref[0, 1])
-    # Fixed-size out-DMA with the same clamp trick: a remainder tile
-    # re-writes rows the previous tile already wrote — bitwise the same
-    # values (both copies are >= ghost from any artificial slab edge).
-    ot = jnp.clip(i * tile, 0, owned - out_dma)
-    out_buf[...] = jax.lax.dynamic_slice_in_dim(
-        res, (lead + ot) - in_off(i), out_dma, 0)
-    cp = pltpu.make_async_copy(out_buf, o_hbm.at[pl.ds(ot, out_dma)],
-                               out_sem)
+    copy_in(i, slot).wait()
+    a0 = in_off(i)
+    compute(in_buf.at[slot], res_buf, *scratch, valid[0] - a0,
+            valid[1] - a0)
+    cp = pltpu.make_async_copy(
+        res_buf.at[pl.ds(pl.multiple_of(lead + i * tile - a0, align),
+                         tile)],
+        o_hbm.at[pl.ds(pl.multiple_of(i * tile, align), tile)], out_sem)
     cp.start()
     cp.wait()
 
@@ -892,7 +908,7 @@ def _kernel_persistent(lim_ref, x_hbm, o_hbm, in_buf, out_buf, in_sems,
                                     "owned", "backend", "apply_fn"))
 def stencil_call_persistent(chunk: jax.Array, spec: StencilSpec, *,
                             bx: int, bt: int, tile: int, lead: int,
-                            owned: int, backend: str = "interpret",
+                            owned: int, backend: str,
                             apply_fn=None) -> jax.Array:
     """``bt`` fused steps over a device-resident chunk slab, streamed
     tile-by-tile through VMEM by the persistent in-kernel DMA pipeline.
@@ -902,9 +918,11 @@ def stencil_call_persistent(chunk: jax.Array, spec: StencilSpec, *,
     host-loop slab); ``lead`` is the number of ghost rows before the
     first owned row (0 when the chunk starts at the true grid edge),
     ``owned`` the number of owned rows, and ``tile`` the in-kernel tile
-    extent. Returns the ``(owned, ...)`` computed rows. Gate with
-    :func:`kernel_pipeline_supported` first — this entry validates but
-    does not fall back.
+    extent (size it with ``core.blocking.persistent_tile``: two tile
+    slabs and one result slab live in VMEM; 2D tiles round to whole
+    sublane tiles). Returns the ``(owned, ...)`` computed rows. Gate
+    with :func:`kernel_pipeline_supported` first — this entry validates
+    but does not fall back.
     """
     if backend not in ("interpret", "pallas"):
         raise ValueError(
@@ -925,15 +943,19 @@ def stencil_call_persistent(chunk: jax.Array, spec: StencilSpec, *,
     if not (0 <= lead and 1 <= owned and lead + owned <= chunk_len):
         raise ValueError(f"invalid chunk geometry: lead={lead} "
                          f"owned={owned} chunk_len={chunk_len}")
-    interpret = backend == "interpret"
-    dma_len = min(tile + 2 * g, chunk_len)
-    out_dma = min(tile, owned)
+    align = _SUBLANE[chunk.dtype.itemsize] if dims == 2 else 1
+    tile = max(align, tile - tile % align)
+    ghost = round_up(g, align)
+    front = -lead % align
     n_inner = -(-owned // tile)
+    n_rows = round_up(max(front + chunk_len, front + lead + n_inner * tile),
+                      align)
+    dma_len = min(tile + 2 * ghost, n_rows)
     true_w = chunk.shape[-1]
-    nt = -(-true_w // bx)
-    wp = nt * bx
-    pad = ((0, 0),) * (dims - 1) + ((0, wp - true_w),)
-    xp = jnp.pad(chunk, pad)
+    wp = round_up(true_w, bx)
+    xp = jnp.pad(chunk, ((front, n_rows - front - chunk_len),)
+                 + ((0, 0),) * (dims - 2) + ((0, wp - true_w),))
+    slab = (dma_len,) + xp.shape[1:]
     if apply_fn is None:
         if dims == 2:
             from repro.kernels.stencil2d import _apply_2d as apply_fn
@@ -942,41 +964,38 @@ def stencil_call_persistent(chunk: jax.Array, spec: StencilSpec, *,
     slab_compute = _slab_compute_2d if dims == 2 else _slab_compute_3d
     compute = functools.partial(slab_compute, spec=spec, bx=bx, bt=bt,
                                 true_w=true_w, apply_fn=apply_fn)
+    scratch = [pltpu.VMEM((2,) + slab, xp.dtype),
+               pltpu.VMEM(slab, xp.dtype)]
+    if dims == 3:
+        scratch.append(pltpu.VMEM(
+            (bt, 2 * spec.radius + 1, xp.shape[1], bx + 2 * g), xp.dtype))
     kern = functools.partial(
-        _kernel_persistent, compute=compute, tile=tile, g=g, lead=lead,
-        owned=owned, chunk_len=chunk_len, dma_len=dma_len,
-        out_dma=out_dma, n_inner=n_inner)
-    # Every DMA'd slab is dma_len real (clipped) leading-axis rows; the
-    # limits ride in a loop-carried operand so they reach the slab
-    # compute *traced*, exactly as the in-core kernels read them.
-    lim = _limits(None, None, dma_len)
+        _kernel_persistent, compute=compute, tile=tile, ghost=ghost,
+        lead=front + lead, valid=(front, front + chunk_len),
+        n_rows=n_rows, dma_len=dma_len, n_inner=n_inner, align=align)
+    limit = vmem_limit(persistent_vmem_bytes(
+        spec, xp.shape[1:], bx=bx, bt=bt, tile=tile,
+        itemsize=xp.dtype.itemsize))
     out = pl.pallas_call(
         kern,
         grid=(n_inner,),
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0)),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((owned,) + xp.shape[1:],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((n_inner * tile,) + xp.shape[1:],
                                        xp.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, dma_len) + xp.shape[1:], xp.dtype),
-            pltpu.VMEM((out_dma,) + xp.shape[1:], xp.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-        compiler_params=compat.compiler_params_for(backend, 1),
-        interpret=interpret,
-    )(lim, xp)
-    return out[..., :true_w]
+        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,)),
+                                  pltpu.SemaphoreType.DMA],
+        compiler_params=compat.compiler_params_for(backend, 1, limit),
+        interpret=backend == "interpret",
+    )(xp)
+    return out[:owned, ..., :true_w]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("specs", "bx", "bt", "variant",
-                                    "interpret", "backend", "apply_fns"))
+                                    "backend", "apply_fns"))
 def stencil_call_program(x: jax.Array, specs, *, bx: int, bt: int,
-                         variant: str = "revolving",
-                         interpret: bool = True,
-                         backend: str | None = None,
+                         backend: str, variant: str = "revolving",
                          source: jax.Array | None = None, aux=None,
                          scalars=None, apply_fns=None,
                          valid_lo=None, valid_hi=None) -> jax.Array:
@@ -1010,7 +1029,7 @@ def stencil_call_program(x: jax.Array, specs, *, bx: int, bt: int,
     every aux operand must then be ``[B, *grid]`` too. Each problem's
     result is bitwise-identical to its solo run.
     """
-    backend = _resolve_engine_backend(backend, interpret)
+    check_backend(backend)
     specs = tuple(specs)
     if not specs:
         raise ValueError("specs must hold at least one StencilSpec")
@@ -1148,8 +1167,7 @@ def stencil_call_program(x: jax.Array, specs, *, bx: int, bt: int,
 
 
 def stencil_call(x: jax.Array, spec: StencilSpec, *, bx: int, bt: int,
-                 variant: str = "revolving", interpret: bool = True,
-                 backend: str | None = None,
+                 backend: str, variant: str = "revolving",
                  source: jax.Array | None = None, aux=None,
                  scalars: jax.Array | None = None,
                  apply_fn=None, valid_lo=None, valid_hi=None) -> jax.Array:
@@ -1163,15 +1181,15 @@ def stencil_call(x: jax.Array, spec: StencilSpec, *, bx: int, bt: int,
     pre-program engine.
     """
     return stencil_call_program(
-        x, (spec,), bx=bx, bt=bt, variant=variant, interpret=interpret,
-        backend=backend, source=source, aux=aux,
+        x, (spec,), bx=bx, bt=bt, variant=variant, backend=backend,
+        source=source, aux=aux,
         scalars=None if scalars is None else (scalars,),
         apply_fns=None if apply_fn is None else (apply_fn,),
         valid_lo=valid_lo, valid_hi=valid_hi)
 
 
 def stencil_call_vmap(x: jax.Array, spec: StencilSpec, *, bx: int, bt: int,
-                      variant: str = "revolving", interpret: bool = True,
+                      backend: str, variant: str = "revolving",
                       source: jax.Array | None = None, aux=None,
                       scalars: jax.Array | None = None,
                       apply_fn=None) -> jax.Array:
@@ -1197,7 +1215,7 @@ def stencil_call_vmap(x: jax.Array, spec: StencilSpec, *, bx: int, bt: int,
 
     def call(x1, src1, aux1, scal1):
         return stencil_call(x1, spec, bx=bx, bt=bt, variant=variant,
-                            interpret=interpret, source=src1, aux=aux1,
+                            backend=backend, source=src1, aux=aux1,
                             scalars=scal1, apply_fn=apply_fn)
 
     in_axes = (0,

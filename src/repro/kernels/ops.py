@@ -248,7 +248,6 @@ def stencil_sweep(x: jax.Array, spec: StencilSpec, bx: int | None = None,
     if backend == "reference":
         return _ref.stencil_multistep(x, spec, bt, source, aux=aux,
                                       scalars=scalars)
-    interpret = backend == "interpret"
     if nd > 1:
         if backend == "gpu":
             raise NotImplementedError(
@@ -260,12 +259,11 @@ def stencil_sweep(x: jax.Array, spec: StencilSpec, bx: int | None = None,
         _count_dispatch()
         return halo.stencil_run_sharded(
             x, spec, bt, n_devices=nd, bx=bx, bt=bt, variant=variant,
-            interpret=interpret, source=source, aux=aux, scalars=scalars,
+            backend=backend, source=source, aux=aux, scalars=scalars,
             devices=devices, overlap=overlap)
     fn = _stencil2d if spec.dims == 2 else _stencil3d
     _count_dispatch()
-    return fn(x, spec, bx=bx, bt=bt, variant=variant,
-              interpret=interpret, backend=backend,
+    return fn(x, spec, bx=bx, bt=bt, variant=variant, backend=backend,
               source=source, aux=aux, scalars=scalars)
 
 
@@ -277,7 +275,8 @@ def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
                 n_devices: int | None = None, devices=None,
                 overlap: bool = True,
                 hbm_budget: int | None = None,
-                pipeline: str = "host") -> jax.Array:
+                pipeline: str = "host",
+                metrics: dict | None = None) -> jax.Array:
     """``n_steps`` total time steps as ceil(n/bt) blocked sweeps.
 
     The trailing partial sweep runs with the remainder temporal degree so
@@ -309,6 +308,9 @@ def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
     (``"host"`` Python-loop double buffering, or ``"kernel"`` for the
     persistent in-kernel DMA pipeline with automatic host fallback —
     see docs/pipelining.md); it is ignored on in-core runs.
+    ``metrics``, when a dict is passed, is filled by the out-of-core
+    runner (pipeline used, fallback reason, tiles — see
+    ``outofcore.stencil_run_outofcore``) if the run routes there.
     """
     backend = _resolve(backend)
     nd = 1 if n_devices is None else n_devices
@@ -338,7 +340,8 @@ def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
                 x, spec, n_steps, bx=bx, bt=bt, variant=variant,
                 backend=backend, hbm_budget=budget,
                 source=source, aux=aux, scalars=scalars,
-                pipeline=pipeline, n_devices=nd, devices=devices)
+                pipeline=pipeline, n_devices=nd, devices=devices,
+                metrics=metrics)
     if scalars is not None:
         import jax.numpy as jnp
         scalars = jnp.asarray(scalars, jnp.float32)
@@ -358,7 +361,7 @@ def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
         _count_dispatch(full + (1 if rem else 0))
         return halo.stencil_run_sharded(
             x, spec, n_steps, n_devices=nd, bx=bx, bt=bt, variant=variant,
-            interpret=backend == "interpret", source=source, aux=aux,
+            backend=backend, source=source, aux=aux,
             scalars=scalars, devices=devices, overlap=overlap)
     full, rem = divmod(n_steps, bt)
     done = 0
@@ -504,7 +507,6 @@ def stencil_program_run(x_or_fields, program, n_steps: int, *,
     if len(groups) > 1:
         bt = 1       # groups must alternate every program step
     bt = max(1, min(bt, n_steps) if n_steps else bt)
-    interpret = backend == "interpret"
 
     from repro.outofcore import route_decision
     grid = primary.shape[1:] if B is not None else primary.shape
@@ -546,7 +548,7 @@ def stencil_program_run(x_or_fields, program, n_steps: int, *,
         _count_dispatch(sum(-(-n_steps // bt) for _ in groups))
         out = halo.stencil_program_run_sharded(
             fields, program, n_steps, n_devices=nd, bx=bx, bt=bt,
-            variant=variant, interpret=interpret, inputs=inputs or None,
+            variant=variant, backend=backend, inputs=inputs or None,
             scalars=scalars or None, devices=devices, overlap=overlap,
             fuse=fuse)
         return out[program.fields[0]] if bare else out
@@ -572,7 +574,7 @@ def stencil_program_run(x_or_fields, program, n_steps: int, *,
             _count_dispatch()
             fields[fname] = engine.stencil_call_program(
                 fields[fname], specs, bx=bx, bt=bts, variant=variant,
-                interpret=interpret, backend=backend, aux=aux or None,
+                backend=backend, aux=aux or None,
                 scalars=(scal if any(c is not None for c in scal)
                          else None))
         done += bts

@@ -71,8 +71,7 @@ _apply_star_2d = _apply_2d
 
 
 def stencil2d(x: jax.Array, spec: StencilSpec, bx: int = 256, bt: int = 1,
-              variant: str = "revolving", interpret: bool = True,
-              backend: str | None = None,
+              variant: str = "revolving", *, backend: str,
               source: jax.Array | None = None, aux=None,
               scalars: jax.Array | None = None) -> jax.Array:
     """Run ``bt`` fused time steps of ``spec`` over a [H, W] grid (or a
@@ -81,6 +80,6 @@ def stencil2d(x: jax.Array, spec: StencilSpec, bx: int = 256, bt: int = 1,
         raise ValueError("stencil2d needs a 2D grid (or a [B, H, W] "
                          "batch) and a 2D spec")
     return engine.stencil_call(x, spec, bx=bx, bt=bt, variant=variant,
-                               interpret=interpret, backend=backend,
+                               backend=backend,
                                source=source, aux=aux, scalars=scalars,
                                apply_fn=_apply_2d)

@@ -79,8 +79,7 @@ _apply_star_3d = _apply_3d
 
 
 def stencil3d(x: jax.Array, spec: StencilSpec, bx: int = 128, bt: int = 1,
-              variant: str = "revolving", interpret: bool = True,
-              backend: str | None = None,
+              variant: str = "revolving", *, backend: str,
               source: jax.Array | None = None, aux=None,
               scalars: jax.Array | None = None) -> jax.Array:
     """Run ``bt`` fused time steps of ``spec`` over a [D, H, W] grid (or
@@ -89,6 +88,6 @@ def stencil3d(x: jax.Array, spec: StencilSpec, bx: int = 128, bt: int = 1,
         raise ValueError("stencil3d needs a 3D grid (or a [B, D, H, W] "
                          "batch) and a 3D spec")
     return engine.stencil_call(x, spec, bx=bx, bt=bt, variant=variant,
-                               interpret=interpret, backend=backend,
+                               backend=backend,
                                source=source, aux=aux, scalars=scalars,
                                apply_fn=_apply_3d)

@@ -179,6 +179,16 @@ def _dispatcher(key, spec, bx, bts, variant, backend, aux_names,
     return fn
 
 
+def _kernel_tile(spec, slab_shape, bx, bt, backend, tile) -> int:
+    """The persistent kernel's in-kernel tile: the host tile, cut down
+    until its DMA slabs fit the device's VMEM budget."""
+    from repro.core.blocking import persistent_tile
+    from repro.core.perf_model import device_spec_for
+    return persistent_tile(spec, tuple(slab_shape), bx=bx, bt=bt,
+                           vmem_budget=device_spec_for(backend).vmem_bytes,
+                           limit=tile)
+
+
 def _slab(a: np.ndarray, start: int, end: int, ax: int) -> np.ndarray:
     """``a[start:end]`` along ``ax`` — slabs are *clipped* to the grid,
     never padded (see the module docstring's exactness note)."""
@@ -202,9 +212,8 @@ def resolve_tile(x_shape, spec: StencilSpec, *, bx: int, bt: int,
 
 
 def stencil_run_outofcore(x, spec: StencilSpec, n_steps: int, *,
-                          bx: int, bt: int, variant: str = "revolving",
-                          interpret: bool = True,
-                          backend: str | None = None,
+                          bx: int, bt: int, backend: str,
+                          variant: str = "revolving",
                           tile: int | None = None,
                           hbm_budget: int | None = None,
                           source=None, aux=None, scalars=None,
@@ -261,8 +270,7 @@ def stencil_run_outofcore(x, spec: StencilSpec, n_steps: int, *,
     mode**; the in-core engine on a forced-small budget is the
     differential oracle in tests.
     """
-    backend = engine._resolve_engine_backend(backend, interpret)
-    interpret = backend == "interpret"
+    engine.check_backend(backend)
     if x.ndim not in (spec.dims, spec.dims + 1):
         raise ValueError(f"grid rank {x.ndim} != spec.dims {spec.dims} "
                          f"(or {spec.dims + 1} with a leading batch axis)")
@@ -324,7 +332,7 @@ def stencil_run_outofcore(x, spec: StencilSpec, n_steps: int, *,
     bt = max(1, min(bt, n_steps))
     full, rem = divmod(n_steps, bt)
     schedule = [bt] * full + ([rem] if rem else [])
-    donate = not interpret
+    donate = backend != "interpret"
     nxt = np.empty_like(cur)
     n_tiles = -(-extent // tile)
 
@@ -397,6 +405,8 @@ def stencil_run_outofcore(x, spec: StencilSpec, n_steps: int, *,
                 K = n_tiles
             K = min(K, n_tiles)
             n_chunks = -(-n_tiles // K)
+            ktile = _kernel_tile(spec, cur.shape[1:], bx, bts, backend,
+                                 tile)
             acc["n_chunks"] = n_chunks
             acc["tiles_per_chunk"] = K
             for ci in range(n_chunks):
@@ -412,7 +422,7 @@ def stencil_run_outofcore(x, spec: StencilSpec, n_steps: int, *,
                 cp0 = time.perf_counter()
                 out = engine.stencil_call_persistent(
                     chunk, spec, bx=bx, bt=bts,
-                    tile=min(tile, end - start), lead=c0 - start,
+                    tile=min(ktile, end - start), lead=c0 - start,
                     owned=c1 - c0, backend=backend)
                 if phased:
                     jax.block_until_ready(out)
@@ -582,6 +592,8 @@ def _stream_sharded(*, cur, spec, schedule, scalars, bx, variant,
                 K = max(tiles_d)
             K = min(K, max(tiles_d))
             chunks_d = [-(-t // K) for t in tiles_d]
+            ktile = _kernel_tile(spec, cur.shape[ga + 1:], bx, bts,
+                                 backend, tile)
             acc["n_chunks"] = sum(chunks_d)
             acc["tiles_per_chunk"] = K
             for ci in range(max(chunks_d)):
@@ -605,7 +617,7 @@ def _stream_sharded(*, cur, spec, schedule, scalars, bx, variant,
                     cp0 = time.perf_counter()
                     out = engine.stencil_call_persistent(
                         chunk, spec, bx=bx, bt=bts,
-                        tile=min(tile, end - start), lead=c0 - start,
+                        tile=min(ktile, end - start), lead=c0 - start,
                         owned=c1 - c0, backend=backend)
                     if phased:
                         jax.block_until_ready(out)

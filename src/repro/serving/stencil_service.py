@@ -38,7 +38,10 @@ Lifecycle (``docs/serving.md`` has the full walk-through):
      re-verifies that per request, for smoke tests.
 
 ``metrics`` tracks dispatches, served/padding problem counts, failed
-requests and the measured device-busy fraction (time with work in
+requests, buckets that failed and were re-served solo
+(``bucket_failures`` — even when every solo retry succeeds, so a
+refused batched kernel is visible), and the measured device-busy
+fraction (time with work in
 flight / wall time) — the quantity batching exists to raise;
 ``benchmarks/serving.py`` turns it into a throughput suite.
 
@@ -163,7 +166,8 @@ class StencilService:
         self._outofcore: set = set()
         self.metrics = {"dispatches": 0, "problems": 0, "pad_rows": 0,
                         "outofcore_dispatches": 0, "failed": 0,
-                        "busy_s": 0.0, "wall_s": 0.0}
+                        "bucket_failures": 0, "busy_s": 0.0,
+                        "wall_s": 0.0}
 
     # ------------------------------------------------------------------
     def submit(self, req: StencilRequest) -> None:
@@ -378,6 +382,7 @@ class StencilService:
                 except Exception:   # noqa: BLE001 — one bad request
                     # (mis-shaped aux, poisonous value) must not sink
                     # its bucket-mates: re-dispatch each one alone.
+                    self.metrics["bucket_failures"] += 1
                     done.extend(self._serve_solo(key, chunk, bucket))
                     continue
                 in_flight.append((key, chunk, bucket, pad, out))
@@ -395,6 +400,7 @@ class StencilService:
                 out = np.asarray(jax.block_until_ready(out))
             except Exception:   # noqa: BLE001 — async dispatch: a
                 # compiled bucket's failure surfaces here, at readback.
+                self.metrics["bucket_failures"] += 1
                 done.extend(self._serve_solo(key, chunk, bucket))
                 continue
             for j, r in enumerate(chunk):

@@ -20,6 +20,7 @@ bigger hardware, with no test edits.
 """
 import json
 import logging
+import types
 
 import numpy as np
 import jax.numpy as jnp
@@ -187,8 +188,9 @@ def test_engine_rejects_unknown_backend():
 
 
 def test_compiler_params_for_selects_per_backend():
-    # TPU params always constructible (kwargs filtered per jax version)
     assert compat.compiler_params_for("pallas", n_grid=2) is not None
+    params = compat.compiler_params_for("pallas", 1, 64 * 2 ** 20)
+    assert params.vmem_limit_bytes == 64 * 2 ** 20
     if not compat.has_gpu_pallas():
         with pytest.raises(ImportError):
             compat.gpu_compiler_params()
@@ -198,16 +200,45 @@ def test_compiler_params_for_selects_per_backend():
 # v8 autotune cache: per-backend device specs + pipeline mode join the key
 # --------------------------------------------------------------------------
 
-def test_device_spec_registry():
+def _devices_of_kind(monkeypatch, kind):
+    monkeypatch.setattr(pm.jax, "devices",
+                        lambda *a: [types.SimpleNamespace(device_kind=kind)])
+
+
+def test_device_spec_registry(monkeypatch):
+    # pallas is looked up by the chip's device kind, not assumed
+    _devices_of_kind(monkeypatch, "TPU v5 lite")
     assert pm.device_spec_for("pallas") is pm.V5E
     assert pm.device_spec_for("interpret") is pm.CPU_HOST
     assert pm.device_spec_for("reference") is pm.CPU_HOST
     assert pm.device_spec_for("gpu") is pm.GPU_GENERIC
-    assert pm.device_spec_for("anything-else") is pm.V5E
+    with pytest.raises(ValueError, match="anything-else"):
+        pm.device_spec_for("anything-else")
     # the CPU host keeps the V5E HBM default so out-of-core routing
     # thresholds stay one number everywhere (outofcore.route_decision)
     assert pm.CPU_HOST.hbm_bytes == pm.V5E.hbm_bytes
     assert pm.CPU_HOST.vmem_bytes == pm.V5E.vmem_bytes
+
+
+def test_device_spec_for_rejects_unknown_device_kind(monkeypatch):
+    # this host's own device (a CPU) is no TPU
+    with pytest.raises(ValueError, match="no device spec"):
+        pm.device_spec_for("pallas")
+    _devices_of_kind(monkeypatch, "TPU v9 imaginary")
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        pm.device_spec_for("pallas")
+
+
+def test_vmem_budget_is_capacity_less_headroom():
+    assert pm.V5E.vmem_capacity == 128 * 2 ** 20
+    assert pm.V5E.vmem_bytes == pm.V5E.vmem_capacity - pm.VMEM_HEADROOM
+
+
+def test_tpu_compiler_params_rejects_unknown_keyword():
+    with pytest.raises(TypeError):
+        compat.tpu_compiler_params(vmem_limit_byte=2 ** 20)
+    assert compat.tpu_compiler_params(
+        vmem_limit_bytes=2 ** 20).vmem_limit_bytes == 2 ** 20
 
 
 def test_cache_version_is_9():

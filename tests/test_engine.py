@@ -35,7 +35,7 @@ def test_engine_2d_radius_variants(radius, variant):
     spec = diffusion(2, radius)
     x = _rand((23, 261), seed=radius)          # odd, non-tile-aligned
     got = engine.stencil_call(x, spec, bx=128, bt=2, variant=variant,
-                              interpret=True)
+                              backend="interpret")
     want = ref.stencil_multistep(x, spec, 2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
@@ -44,7 +44,7 @@ def test_engine_2d_radius_variants(radius, variant):
 def test_engine_3d_radius(radius):
     spec = diffusion(3, radius)
     x = _rand((6, 11, 263), seed=radius)       # odd in every dim
-    got = engine.stencil_call(x, spec, bx=128, bt=1, interpret=True)
+    got = engine.stencil_call(x, spec, bx=128, bt=1, backend="interpret")
     want = ref.stencil_multistep(x, spec, 1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
@@ -52,7 +52,7 @@ def test_engine_3d_radius(radius):
 def test_engine_3d_temporal_pipeline():
     spec = diffusion(3, 1)
     x = _rand((7, 10, 260))
-    got = engine.stencil_call(x, spec, bx=128, bt=3, interpret=True)
+    got = engine.stencil_call(x, spec, bx=128, bt=3, backend="interpret")
     want = ref.stencil_multistep(x, spec, 3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
@@ -64,7 +64,7 @@ def test_engine_source_term_both_variants():
     want = ref.stencil_multistep(x, spec, 2, src)
     for variant in engine.VARIANTS_2D:
         got = engine.stencil_call(x, spec, bx=128, bt=2, variant=variant,
-                                  interpret=True, source=src)
+                                  backend="interpret", source=src)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    **TOL)
 
@@ -74,11 +74,11 @@ def test_engine_rejects_unknown_variant():
     x = _rand((8, 128))
     with pytest.raises(ValueError, match="variant"):
         engine.stencil_call(x, spec, bx=128, bt=1, variant="bogus",
-                            interpret=True)
+                            backend="interpret")
     x3 = _rand((4, 8, 128))
     with pytest.raises(ValueError, match="variant"):
         engine.stencil_call(x3, diffusion(3, 1), bx=128, bt=1,
-                            variant="multioperand", interpret=True)
+                            variant="multioperand", backend="interpret")
 
 
 # ---------------------------------------------------------------------------
@@ -98,10 +98,10 @@ def test_engine_batched_bitwise_equals_solo_loop(dims, boundary):
         for B in (1, 2, 5):
             x = _rand((B,) + shape, seed=radius * 10 + B)
             got = engine.stencil_call(x, spec, bx=128, bt=2,
-                                      interpret=True)
+                                      backend="interpret")
             solo = jnp.stack([
                 engine.stencil_call(x[b], spec, bx=128, bt=2,
-                                    interpret=True) for b in range(B)])
+                                    backend="interpret") for b in range(B)])
             np.testing.assert_array_equal(
                 np.asarray(got), np.asarray(solo),
                 err_msg=f"dims={dims} {boundary} r={radius} B={B}")
@@ -115,8 +115,9 @@ def test_engine_batched_matches_vmap_fallback(variant):
     spec = diffusion(2, 2)
     x = _rand((3, 13, 140), seed=7)
     got = engine.stencil_call(x, spec, bx=128, bt=2, variant=variant,
-                              interpret=True)
-    vm = engine.stencil_call_vmap(x, spec, bx=128, bt=2, variant=variant)
+                              backend="interpret")
+    vm = engine.stencil_call_vmap(x, spec, bx=128, bt=2, variant=variant,
+                                  backend="interpret")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(vm))
 
 
@@ -124,18 +125,19 @@ def test_engine_batched_source_and_3d():
     spec = hotspot2d()
     x = _rand((4, 13, 140), seed=1)
     src = _rand((4, 13, 140), seed=2) * 0.1
-    got = engine.stencil_call(x, spec, bx=128, bt=2, interpret=True,
+    got = engine.stencil_call(x, spec, bx=128, bt=2, backend="interpret",
                               source=src)
     solo = jnp.stack([
-        engine.stencil_call(x[b], spec, bx=128, bt=2, interpret=True,
+        engine.stencil_call(x[b], spec, bx=128, bt=2, backend="interpret",
                             source=src[b]) for b in range(4)])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(solo))
     spec3 = diffusion(3, 1)
     x3 = _rand((2, 4, 8, 133), seed=3)
     s3 = _rand((2, 4, 8, 133), seed=4) * 0.1
-    got3 = engine.stencil_call(x3, spec3, bx=128, bt=2, interpret=True,
+    got3 = engine.stencil_call(x3, spec3, bx=128, bt=2, backend="interpret",
                                source=s3)
-    vm3 = engine.stencil_call_vmap(x3, spec3, bx=128, bt=2, source=s3)
+    vm3 = engine.stencil_call_vmap(x3, spec3, bx=128, bt=2, source=s3,
+                                   backend="interpret")
     np.testing.assert_array_equal(np.asarray(got3), np.asarray(vm3))
 
 
@@ -143,12 +145,13 @@ def test_engine_batched_rejects_bad_ranks():
     spec = diffusion(2, 1)
     with pytest.raises(ValueError, match="batch"):
         engine.stencil_call(_rand((2, 2, 8, 128)), spec, bx=128, bt=1,
-                            interpret=True)
+                            backend="interpret")
     with pytest.raises(ValueError, match="at least one"):
         engine.stencil_call(jnp.zeros((0, 8, 128)), spec, bx=128, bt=1,
-                            interpret=True)
+                            backend="interpret")
     with pytest.raises(ValueError, match="rank"):
-        engine.stencil_call_vmap(_rand((8, 128)), spec, bx=128, bt=1)
+        engine.stencil_call_vmap(_rand((8, 128)), spec, bx=128, bt=1,
+                                 backend="interpret")
 
 
 def test_ops_batched_autotuned_run():
@@ -248,6 +251,35 @@ def test_autotune_vmem_budget_not_served_stale_from_cache():
     assert small.source != "cache"
     assert small.block_plan.vmem_bytes() <= 2 ** 20
     assert big.block_plan.vmem_bytes() > 0
+
+
+def test_autotune_raises_with_reasons_when_every_candidate_fails(
+        monkeypatch):
+    def refused(*a, **k):
+        raise RuntimeError("Ran out of memory in memory space vmem")
+    monkeypatch.setattr(ops, "stencil_run", refused)
+    with pytest.raises(RuntimeError) as e:
+        autotune.plan((16, 256), diffusion(2, 1), backend="reference",
+                      top_k=2, measure=True)
+    msg = str(e.value)
+    assert "every candidate" in msg
+    assert msg.count("Ran out of memory in memory space vmem") == 2
+
+
+def test_autotune_records_refused_candidates(monkeypatch):
+    real = ops.stencil_run
+
+    def refuse_bt1(x, spec, n, **kw):
+        if kw["bt"] == 1:
+            raise RuntimeError("refused")
+        return real(x, spec, n, **kw)
+    monkeypatch.setattr(ops, "stencil_run", refuse_bt1)
+    tuned = autotune.plan((16, 256), diffusion(2, 1), backend="reference",
+                          top_k=1 << 10, measure=True)
+    assert tuned.source == "measured" and tuned.bt != 1
+    assert tuned.failures
+    assert all(bt == 1 and "refused" in why
+               for (_, bt, _), why in tuned.failures.items())
 
 
 def test_autotune_large_grids_skip_measurement():

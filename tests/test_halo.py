@@ -100,7 +100,7 @@ def test_halo_source_term_and_overlap_schedules():
         for ov in (True, False):
             got = halo.stencil_run_sharded(x, spec, 4, n_devices=4,
                                            bx=128, bt=2, source=src,
-                                           overlap=ov)
+                                           overlap=ov, backend="interpret")
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), """ + TOL + """)
             outs[ov] = np.asarray(got)
@@ -111,7 +111,7 @@ def test_halo_source_term_and_overlap_schedules():
         spec3 = diffusion(3, 1)
         want3 = ref.stencil_multistep(x3, spec3, 4, s3)
         got3 = halo.stencil_run_sharded(x3, spec3, 4, n_devices=4,
-                                        bx=128, bt=2, source=s3)
+                                        bx=128, bt=2, source=s3, backend="interpret")
         np.testing.assert_allclose(
             np.asarray(got3), np.asarray(want3), """ + TOL + """)
         print("OK")
@@ -136,7 +136,7 @@ def test_halo_overlap_parity_3d_and_program():
             spec = diffusion(3, radius)
             outs = {ov: np.asarray(halo.stencil_run_sharded(
                         x3, spec, 5, n_devices=4, bx=128, bt=2,
-                        overlap=ov)) for ov in (True, False)}
+                        overlap=ov, backend="interpret")) for ov in (True, False)}
             np.testing.assert_array_equal(
                 outs[True], outs[False], err_msg=f"3d r={radius}")
         # Multi-field program: groups alternate, per-dispatch exchange.
@@ -146,7 +146,7 @@ def test_halo_overlap_parity_3d_and_program():
                            name="p")
         outs = {ov: np.asarray(halo.stencil_program_run_sharded(
                     {"u": x}, p, 3, n_devices=4, bx=128,
-                    overlap=ov)["u"]) for ov in (True, False)}
+                    overlap=ov, backend="interpret")["u"]) for ov in (True, False)}
         np.testing.assert_array_equal(outs[True], outs[False])
         print("OK")
     """, devices=4)
@@ -168,7 +168,7 @@ def test_halo_extreme_shard_sizes():
         x = jnp.asarray(rng.standard_normal((13, 140)), jnp.float32)
         want = ref.stencil_multistep(x, spec, 4)
         got = halo.stencil_run_sharded(x, spec, 4, n_devices=4,
-                                       bx=128, bt=2)
+                                       bx=128, bt=2, backend="interpret")
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), """ + TOL + """)
         # S == 2h exactly (16 rows over 2 devices, h=4): the overlapped
@@ -176,7 +176,7 @@ def test_halo_extreme_shard_sizes():
         x2 = jnp.asarray(rng.standard_normal((16, 140)), jnp.float32)
         want2 = ref.stencil_multistep(x2, spec, 2)
         got2 = halo.stencil_run_sharded(x2, spec, 2, n_devices=2,
-                                        bx=128, bt=2, overlap=True)
+                                        bx=128, bt=2, overlap=True, backend="interpret")
         np.testing.assert_allclose(
             np.asarray(got2), np.asarray(want2), """ + TOL + """)
         print("OK")
@@ -207,12 +207,12 @@ def test_halo_batched_grid_sharding_parity():
             want = ref.stencil_multistep(x, spec, 5)
             for bt in (1, 4):
                 got = halo.stencil_run_sharded(x, spec, 5, n_devices=4,
-                                               bx=128, bt=bt)
+                                               bx=128, bt=bt, backend="interpret")
                 np.testing.assert_allclose(
                     np.asarray(got), np.asarray(want), """ + TOL + """,
                     err_msg=f"B={B} bt={bt}")
                 solo = jnp.stack([halo.stencil_run_sharded(
-                    x[b], spec, 5, n_devices=4, bx=128, bt=bt)
+                    x[b], spec, 5, n_devices=4, bx=128, bt=bt, backend="interpret")
                     for b in range(B)])
                 np.testing.assert_array_equal(
                     np.asarray(got), np.asarray(solo),
@@ -237,7 +237,7 @@ def test_halo_batch_axis_sharding_parity_and_scalars():
         x = jnp.asarray(rng.standard_normal((8, 21, 140)), jnp.float32)
         assert halo.shard_strategy(x.shape, spec, 4) == "batch"
         got = halo.stencil_run_sharded(x, spec, 5, n_devices=4,
-                                       bx=128, bt=2)
+                                       bx=128, bt=2, backend="interpret")
         want = ref.stencil_multistep(x, spec, 5)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    """ + TOL + """)
@@ -270,7 +270,7 @@ def test_halo_batch_axis_sharding_parity_and_scalars():
         spec3 = diffusion(3, 1)
         assert halo.shard_strategy(x3.shape, spec3, 4) == "batch"
         got3 = halo.stencil_run_sharded(x3, spec3, 4, n_devices=4,
-                                        bx=128, bt=2, source=s3)
+                                        bx=128, bt=2, source=s3, backend="interpret")
         want3 = ref.stencil_multistep(x3, spec3, 4, s3)
         np.testing.assert_allclose(np.asarray(got3), np.asarray(want3),
                                    """ + TOL + """)
@@ -358,7 +358,7 @@ def test_sharded_generic_path_on_one_device():
     want = ref.stencil_multistep(x, spec, 4)
     for ov in (True, False):
         got = halo.stencil_run_sharded(x, spec, 4, n_devices=1, bx=128,
-                                       bt=2, overlap=ov)
+                                       bt=2, overlap=ov, backend="interpret")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=5e-5, atol=5e-5)
 
@@ -368,7 +368,7 @@ def test_sharded_rejects_missing_devices():
     from repro.distributed import halo
     x = jnp.zeros((16, 128), jnp.float32)
     with pytest.raises(ValueError, match="devices"):
-        halo.stencil_run_sharded(x, diffusion(2, 1), 1, n_devices=4096)
+        halo.stencil_run_sharded(x, diffusion(2, 1), 1, n_devices=4096, backend="interpret")
 
 
 def test_sharded_rejects_radius_deeper_than_shard():
@@ -393,7 +393,7 @@ def test_sharded_runner_is_memoized():
     before = len(halo._RUNNERS)
     for _ in range(3):
         x = jnp.asarray(rng.standard_normal((20, 140)), jnp.float32)
-        halo.stencil_run_sharded(x, spec, 2, n_devices=1, bx=128, bt=2)
+        halo.stencil_run_sharded(x, spec, 2, n_devices=1, bx=128, bt=2, backend="interpret")
     assert len(halo._RUNNERS) == before + 1
 
 

@@ -90,7 +90,7 @@ def test_ghost_deeper_than_tile_stays_exact():
     want = np.asarray(ops.stencil_run(x, spec, 4, bx=128, bt=4,
                                       backend="interpret"))
     got = stencil_run_outofcore(x, spec, 4, bx=128, bt=4,
-                                interpret=True, tile=1)
+                                backend="interpret", tile=1)
     np.testing.assert_array_equal(got, want)
 
 
@@ -101,7 +101,7 @@ def test_tile_not_dividing_extent_and_single_tile():
                                       backend="interpret"))
     for tile in (7, 36, 37):        # remainder tile / near-full / full
         got = stencil_run_outofcore(x, spec, 3, bx=128, bt=2,
-                                    interpret=True, tile=tile)
+                                    backend="interpret", tile=tile)
         np.testing.assert_array_equal(got, want, err_msg=f"tile={tile}")
 
 
@@ -198,7 +198,7 @@ def test_runner_does_not_mutate_host_input():
     spec = diffusion(2, 1)
     x = np.asarray(_rand((40, 140)))
     before = x.copy()
-    stencil_run_outofcore(x, spec, 4, bx=128, bt=1, interpret=True,
+    stencil_run_outofcore(x, spec, 4, bx=128, bt=1, backend="interpret",
                           tile=10)      # 4 sweeps: both buffers written
     np.testing.assert_array_equal(x, before)
 
@@ -207,18 +207,18 @@ def test_runner_validates_like_the_engine():
     spec = _varcoef_spec()
     x = _rand((40, 140))
     with pytest.raises(ValueError, match="requires aux"):
-        stencil_run_outofcore(x, spec, 2, bx=128, bt=1, interpret=True,
+        stencil_run_outofcore(x, spec, 2, bx=128, bt=1, backend="interpret",
                               tile=8)
     with pytest.raises(ValueError, match="unknown aux"):
         stencil_run_outofcore(x, diffusion(2, 1), 2, bx=128, bt=1,
-                              interpret=True, tile=8,
+                              backend="interpret", tile=8,
                               aux={"nope": x})
     with pytest.raises(ValueError, match="tile must be in"):
         stencil_run_outofcore(x, diffusion(2, 1), 2, bx=128, bt=1,
-                              interpret=True, tile=41)
+                              backend="interpret", tile=41)
     with pytest.raises(ValueError, match="tile= or hbm_budget="):
         stencil_run_outofcore(x, diffusion(2, 1), 2, bx=128, bt=1,
-                              interpret=True)
+                              backend="interpret")
 
 
 def test_outofcore_with_sharding_composes(monkeypatch):

@@ -100,7 +100,7 @@ def test_sharded_outofcore_parity_3d_matrix():
                         backend="interpret"))
                     m = {}
                     got = stencil_run_outofcore(
-                        x, spec, 5, bx=128, bt=bt, interpret=True,
+                        x, spec, 5, bx=128, bt=bt, backend="interpret",
                         tile=3, n_devices=4, metrics=m)
                     assert m["n_devices"] == 4, m
                     assert m["slab_extents"] == [10, 10, 10, 9], m
@@ -134,7 +134,7 @@ def test_sharded_operands_scalars_batched():
             x, spec, 4, bx=128, bt=2, backend="interpret",
             aux={"power": p}))
         got = stencil_run_outofcore(
-            x, spec, 4, bx=128, bt=2, interpret=True, tile=5,
+            x, spec, 4, bx=128, bt=2, backend="interpret", tile=5,
             n_devices=4, aux={"power": p})
         np.testing.assert_array_equal(got, want, err_msg="aux")
 
@@ -144,7 +144,7 @@ def test_sharded_operands_scalars_batched():
         want = np.asarray(ops.stencil_run(
             x, spec2, 4, bx=128, bt=2, backend="interpret", source=s))
         got = stencil_run_outofcore(
-            x, spec2, 4, bx=128, bt=2, interpret=True, tile=5,
+            x, spec2, 4, bx=128, bt=2, backend="interpret", tile=5,
             n_devices=4, source=s)
         np.testing.assert_array_equal(got, want, err_msg="source")
 
@@ -164,7 +164,7 @@ def test_sharded_operands_scalars_batched():
             x, spec3, 4, bx=128, bt=2, backend="interpret",
             aux={"k": k}, scalars=scal))
         got = stencil_run_outofcore(
-            x, spec3, 4, bx=128, bt=2, interpret=True, tile=5,
+            x, spec3, 4, bx=128, bt=2, backend="interpret", tile=5,
             n_devices=4, aux={"k": k}, scalars=scal)
         np.testing.assert_array_equal(got, want, err_msg="scalars")
 
@@ -174,7 +174,7 @@ def test_sharded_operands_scalars_batched():
         want = np.asarray(ops.stencil_run(
             xb, spec2, 4, bx=128, bt=2, backend="interpret"))
         got = stencil_run_outofcore(
-            xb, spec2, 4, bx=128, bt=2, interpret=True, tile=5,
+            xb, spec2, 4, bx=128, bt=2, backend="interpret", tile=5,
             n_devices=4, metrics=m)
         assert m["n_devices"] == 4, m
         np.testing.assert_array_equal(got, want, err_msg="batched")
@@ -228,7 +228,7 @@ def test_sharded_kernel_pipeline():
             x, spec, 3, bx=128, bt=2, backend="interpret"))
         m = {}
         got = stencil_run_outofcore(
-            x, spec, 3, bx=128, bt=2, interpret=True, tile=6,
+            x, spec, 3, bx=128, bt=2, backend="interpret", tile=6,
             n_devices=4, pipeline="kernel", metrics=m)
         assert m["pipeline_requested"] == "kernel"
         if engine.kernel_pipeline_available("interpret")[0]:
@@ -261,12 +261,12 @@ def test_program_batched_indivisible_falls_back_to_grid():
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
             got = halo.stencil_program_run_sharded(
-                {"u": xb}, p, 3, n_devices=4, bx=128)["u"]
+                {"u": xb}, p, 3, n_devices=4, bx=128, backend="interpret")["u"]
         assert any("falling back" in str(x.message) for x in w), \\
             [str(x.message) for x in w]
         # bitwise parity vs the solo Python loop over problems
         solo = jnp.stack([halo.stencil_program_run_sharded(
-            {"u": xb[b]}, p, 3, n_devices=4, bx=128)["u"]
+            {"u": xb[b]}, p, 3, n_devices=4, bx=128, backend="interpret")["u"]
             for b in range(3)])
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(solo))
@@ -276,7 +276,7 @@ def test_program_batched_indivisible_falls_back_to_grid():
         with warnings.catch_warnings(record=True) as w2:
             warnings.simplefilter("always")
             halo.stencil_program_run_sharded(
-                {"u": xb4}, p, 2, n_devices=4, bx=128)
+                {"u": xb4}, p, 2, n_devices=4, bx=128, backend="interpret")
         assert not [x for x in w2
                     if "falling back" in str(x.message)]
         print("OK")
@@ -329,7 +329,7 @@ def test_solo_metrics_carry_sharding_fields():
         (40, 140)).astype(np.float32)
     m: dict = {}
     stencil_run_outofcore(x, diffusion(2, 1), 2, bx=128, bt=1,
-                          interpret=True, tile=10, metrics=m)
+                          backend="interpret", tile=10, metrics=m)
     assert m["n_devices"] == 1
     assert m["slab_extents"] == [40]
     assert m["halo_rows_exchanged"] == 0

@@ -92,8 +92,10 @@ def test_select_config_prunes_to_top_k():
     spec = diffusion(2, 1)
     plans = pm.select_config(spec, (4096, 16384), n_steps=64, top_k=3)
     assert len(plans) == 3
-    # returned plans are sorted by predicted time
-    times = [pm.stencil_roofline(p, 64).t_predicted for p in plans]
+    # returned plans are sorted by predicted time: the roofline plus
+    # the modeled dispatch time, which the ranking charges every plan
+    terms = [pm.stencil_roofline(p, 64) for p in plans]
+    times = [t.t_predicted + t.t_dispatch for t in terms]
     assert times == sorted(times)
 
 

@@ -39,7 +39,7 @@ def test_persistent_bitwise_vs_incore(dims, radius, bt, boundary):
     rng = np.random.default_rng(0)
     x = _grid(dims, rng)
     spec = diffusion(dims, radius, boundary=boundary)
-    want = engine.stencil_call(x, spec, bx=BX, bt=bt, interpret=True)
+    want = engine.stencil_call(x, spec, bx=BX, bt=bt, backend="interpret")
     got = engine.stencil_call_persistent(
         x, spec, bx=BX, bt=bt, tile=9, lead=0, owned=x.shape[0],
         backend="interpret")
@@ -56,7 +56,7 @@ def test_persistent_chunk_with_lead_ghost():
     x = _grid(2, rng)
     spec = diffusion(2, 1)
     bt, g = 2, 2                       # ghost depth bt*r
-    want = engine.stencil_call(x, spec, bx=BX, bt=bt, interpret=True,
+    want = engine.stencil_call(x, spec, bx=BX, bt=bt, backend="interpret",
                                valid_lo=None, valid_hi=None)
     # Chunk covering grid rows [20, 50) with g ghosts each side.
     c0, c1 = 20, 50
@@ -103,14 +103,14 @@ def test_runner_kernel_mode_bitwise(dims, budget):
     rng = np.random.default_rng(2)
     x = _grid(dims, rng)
     spec = diffusion(dims, 1)
-    kw = dict(bx=BX, bt=2, interpret=True)
+    kw = dict(bx=BX, bt=2, backend="interpret")
     if budget is None:
         kw["tile"] = 9
     else:
         kw["hbm_budget"] = budget
-    want = engine.stencil_call(x, spec, bx=BX, bt=2, interpret=True)
+    want = engine.stencil_call(x, spec, bx=BX, bt=2, backend="interpret")
     want = np.asarray(engine.stencil_call(
-        np.asarray(want), spec, bx=BX, bt=1, interpret=True))  # 3 steps
+        np.asarray(want), spec, bx=BX, bt=1, backend="interpret"))  # 3 steps
     host = stencil_run_outofcore(x, spec, 3, pipeline="host", **kw)
     np.testing.assert_array_equal(np.asarray(host), want)
     m: dict = {}
@@ -133,12 +133,12 @@ def test_runner_kernel_fallback_paths():
     src = jnp.asarray(rng.standard_normal(x.shape), jnp.float32) * 0.1
     m: dict = {}
     got = stencil_run_outofcore(x, spec, 2, bx=BX, bt=1, tile=16,
-                                interpret=True, source=src,
+                                backend="interpret", source=src,
                                 pipeline="kernel", metrics=m)
     assert m["pipeline_requested"] == "kernel"
     assert m["pipeline"] == "host" and m["fallback_reason"]
     want = stencil_run_outofcore(x, spec, 2, bx=BX, bt=1, tile=16,
-                                 interpret=True, source=src)
+                                 backend="interpret", source=src)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     old = os.environ.get("REPRO_DISABLE_KERNEL_PIPELINE")
@@ -146,11 +146,11 @@ def test_runner_kernel_fallback_paths():
     try:
         m2: dict = {}
         got2 = stencil_run_outofcore(x, spec, 2, bx=BX, bt=1, tile=16,
-                                     interpret=True, pipeline="kernel",
+                                     backend="interpret", pipeline="kernel",
                                      metrics=m2)
         assert m2["pipeline"] == "host" and m2["fallback_reason"]
         want2 = stencil_run_outofcore(x, spec, 2, bx=BX, bt=1, tile=16,
-                                      interpret=True)
+                                      backend="interpret")
         np.testing.assert_array_equal(np.asarray(got2), np.asarray(want2))
     finally:
         if old is None:
@@ -163,7 +163,7 @@ def test_runner_rejects_unknown_pipeline():
     x = _grid(2, np.random.default_rng(4))
     with pytest.raises(ValueError, match="pipeline"):
         stencil_run_outofcore(x, diffusion(2, 1), 1, bx=BX, bt=1,
-                              tile=16, interpret=True, pipeline="dma")
+                              tile=16, backend="interpret", pipeline="dma")
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +176,7 @@ def test_metrics_phased_at_depth_1(pipeline):
     x = _grid(2, rng)
     m: dict = {}
     stencil_run_outofcore(x, diffusion(2, 1), 2, bx=BX, bt=1, tile=16,
-                          interpret=True, depth=1, pipeline=pipeline,
+                          backend="interpret", depth=1, pipeline=pipeline,
                           metrics=m)
     for k in ("pipeline_requested", "pipeline", "fallback_reason",
               "tile", "depth", "n_tiles", "n_sweeps", "n_dispatches",
@@ -196,7 +196,7 @@ def test_metrics_overlapped_depth_skips_phases():
     x = _grid(2, rng)
     m: dict = {}
     stencil_run_outofcore(x, diffusion(2, 1), 2, bx=BX, bt=1, tile=16,
-                          interpret=True, depth=2, metrics=m)
+                          backend="interpret", depth=2, metrics=m)
     # In-flight transfers make per-phase attribution meaningless.
     assert m["upload_s"] is None and m["readback_s"] is None
     assert m["wall_s"] > 0

@@ -228,11 +228,11 @@ def test_fused_program_call_equals_per_sweep_calls():
     p = _pair()
     x = _rand((40, 200), seed=1)
     specs = tuple(s.spec for s in p.sweeps)
-    fused = engine.stencil_call_program(x, specs, bx=128, bt=2)
+    fused = engine.stencil_call_program(x, specs, bx=128, bt=2, backend="interpret")
     loop = x
     for _ in range(2):
         for sp in specs:
-            loop = engine.stencil_call(loop, sp, bx=128, bt=1)
+            loop = engine.stencil_call(loop, sp, bx=128, bt=1, backend="interpret")
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(loop))
 
 
@@ -240,7 +240,7 @@ def test_fused_halo_exceeding_tile_is_loud():
     specs = tuple(s.spec for s in _pair().sweeps)
     with pytest.raises(ValueError, match="exceeds the tile width"):
         engine.stencil_call_program(_rand((40, 200)), specs, bx=128,
-                                    bt=64)
+                                    bt=64, backend="interpret")
 
 
 def test_run_fuse_true_equals_fuse_false_bitwise():
@@ -585,7 +585,7 @@ def test_sharded_program_batch_strategy_4dev():
                             Sweep("b", diffusion(2, 2))), name="p")
         out = halo.stencil_program_run_sharded({"u": xb}, p, 3,
                                                n_devices=4, bx=128,
-                                               bt=2)
+                                               bt=2, backend="interpret")
         want = ref.stencil_program_multistep({"u": xb}, p, 3)["u"]
         np.testing.assert_allclose(np.asarray(out["u"]),
                                    np.asarray(want),
@@ -596,7 +596,7 @@ def test_sharded_program_batch_strategy_4dev():
         with warnings.catch_warnings(record=True) as rec:
             warnings.simplefilter("always")
             out3 = halo.stencil_program_run_sharded({"u": xb[:3]}, p, 3,
-                                                    n_devices=4, bx=128)
+                                                    n_devices=4, bx=128, backend="interpret")
         assert any("falling back" in str(w.message) for w in rec), \
             [str(w.message) for w in rec]
         want3 = ref.stencil_program_multistep({"u": xb[:3]}, p, 3)["u"]

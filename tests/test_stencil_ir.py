@@ -96,7 +96,7 @@ def test_golden_2d(radius, boundary):
     np.testing.assert_allclose(np.asarray(got_ref), want, **TOL)
     for variant in engine.VARIANTS_2D:
         got = engine.stencil_call(x, spec, bx=128, bt=2, variant=variant,
-                                  interpret=True)
+                                  backend="interpret")
         np.testing.assert_allclose(np.asarray(got), want, **TOL,
                                    err_msg=f"{boundary} r={radius} {variant}")
 
@@ -109,7 +109,7 @@ def test_golden_3d(radius, boundary):
     want = np_multistep(np.asarray(x, np.float32), spec, 2)
     got_ref = ref.stencil_multistep(x, spec, 2)
     np.testing.assert_allclose(np.asarray(got_ref), want, **TOL)
-    got = engine.stencil_call(x, spec, bx=128, bt=2, interpret=True)
+    got = engine.stencil_call(x, spec, bx=128, bt=2, backend="interpret")
     np.testing.assert_allclose(np.asarray(got), want, **TOL,
                                err_msg=f"{boundary} r={radius}")
 
@@ -244,13 +244,15 @@ def test_sharded_runner_rejects_unknown_operands():
     x = _rand((16, 140))
     with pytest.raises(ValueError, match="unknown aux"):
         halo.stencil_run_sharded(x, diffusion(2, 1), 2, n_devices=1,
-                                 bx=128, bt=1, aux={"bogus": x})
+                                 bx=128, bt=1, backend="interpret",
+                                 aux={"bogus": x})
     with pytest.raises(ValueError, match="shape"):
         halo.stencil_run_sharded(
             x, StencilSpec(dims=2, radius=1, center=1.0,
                            axis_weights=((0.0,) * 3,) * 2,
                            aux=(AuxOperand("s"),), name="s1"),
-            2, n_devices=1, bx=128, bt=1, aux={"s": _rand((8, 140))})
+            2, n_devices=1, bx=128, bt=1, backend="interpret",
+            aux={"s": _rand((8, 140))})
 
 
 def test_srad_blocked_resolves_blocking_once(tmp_path, monkeypatch):
@@ -670,12 +672,11 @@ def _check_ir_problem(dims, layout, radius, boundary, with_src, B, bt,
         want.append(g)
     want = np.stack(want)
 
-    kw = dict(bx=128, bt=bt, interpret=True, aux=aux, scalars=scalars,
+    kw = dict(bx=128, bt=bt, backend="interpret", aux=aux, scalars=scalars,
               source=None if src is None else jnp.asarray(src))
     got = engine.stencil_call(x, spec, **kw)
     np.testing.assert_allclose(np.asarray(got), want,
                                rtol=1e-4, atol=1e-4)
-    kw.pop("interpret")
     vm = engine.stencil_call_vmap(x, spec, **kw)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(vm))
 
