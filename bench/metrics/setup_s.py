@@ -1,0 +1,5 @@
+"""End to end: seconds from process start to the first timed call."""
+
+
+def read(run):
+    return run.setup_s
