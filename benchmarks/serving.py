@@ -14,7 +14,7 @@ paths over the same request set:
 Both are warmed first so compile time is excluded; the speedup is pure
 dispatch amortization + batched execution. Results are printed as
 benchmark rows and written to ``BENCH_serving.json`` (requests/s per
-path, speedup, measured device-busy fraction, dispatch counts).
+path, speedup, dispatch counts).
 
 ``--smoke`` runs a tiny workload with the service's ``check=True``
 parity gate on (every served result asserted bitwise-equal to its solo
@@ -135,11 +135,9 @@ def run(smoke: bool = False) -> list[dict]:
          "requests_per_s": rps_solo},
         {"name": "serving_batched", "us": t_batch / n * 1e6,
          "derived": (f"{rps_batch:.1f} req/s speedup={speedup:.2f}x "
-                     f"busy={svc.device_busy_fraction:.2f} "
                      f"dispatches={svc.metrics['dispatches']} "
                      f"pad={svc.metrics['pad_rows']}"),
          "requests_per_s": rps_batch, "speedup": speedup,
-         "device_busy_fraction": svc.device_busy_fraction,
          "dispatches": svc.metrics["dispatches"],
          "pad_rows": svc.metrics["pad_rows"]},
     ]

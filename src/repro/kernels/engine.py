@@ -32,7 +32,10 @@ dimension-*specific* arithmetic is injected as a plugin:
   * ``pallas_call`` assembly: grids, Block/scratch specs, compiler
     params (all experimental-jax symbols come through ``repro.compat``,
     per the README shim policy), padding to lane/sublane tiles and
-    cropping back;
+    cropping back; each ``pallas_call`` carries a stable ``name``
+    (``stencil2d_multioperand``, ``stencil2d_revolving``,
+    ``stencil3d_stream``, ``stencil_persistent``), which becomes the
+    kernel's instruction name in the HLO and in a device trace;
   * the *leading-axis validity interval*: every kernel receives a tiny
     ``(1, 2)`` int32 operand ``[lo, hi)`` bounding the valid rows (2D)
     or planes (3D) of the leading axis. Cells outside the interval are
@@ -550,6 +553,7 @@ def _run_2d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
             out_shape=jax.ShapeDtypeStruct(xp.shape, xp.dtype),
             compiler_params=params,
             interpret=interpret,
+            name="stencil2d_multioperand",
         )(*(head_args + [a for a in streamed for _ in range(3)]))
     elif variant == "revolving":
         kern = functools.partial(_kernel_2d_revolving, **kern_kw)
@@ -567,6 +571,7 @@ def _run_2d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
             scratch_shapes=scratch,
             compiler_params=params,
             interpret=interpret,
+            name="stencil2d_revolving",
         )(*(head_args + streamed))
     else:
         raise ValueError(f"unknown 2D variant {variant!r}; "
@@ -633,6 +638,7 @@ def _run_3d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
             _vmem_limit(specs, rows, bx, bt, fill, 1 + has_src,
                         variant, x.dtype)),
         interpret=interpret,
+        name="stencil3d_stream",
     )(*((lim, xp, xp, xp, sp, sp, sp) if has_src else (lim, xp, xp, xp)))
     return out[..., :true_d, :true_h, :true_w]
 
@@ -987,6 +993,7 @@ def stencil_call_persistent(chunk: jax.Array, spec: StencilSpec, *,
                                   pltpu.SemaphoreType.DMA],
         compiler_params=compat.compiler_params_for(backend, 1, limit),
         interpret=backend == "interpret",
+        name="stencil_persistent",
     )(xp)
     return out[:owned, ..., :true_w]
 

@@ -51,11 +51,17 @@ the host-streaming tiled runner (``repro.outofcore`` —
 docs/outofcore.md): host memory holds the grid, leading-axis tiles
 with deep ghosts stream through the device, and the result comes back
 as a host numpy array, bitwise-equal to the in-core engine.
+
+Spans (``repro.spans``, recorded only under a profiler session):
+``ops.stencil_run`` covers a whole run; inside it ``ops.plan`` covers
+the blocking resolution and the out-of-core routing decision, and one
+``ops.sweep`` per blocked sweep covers the engine call that enqueues it.
 """
 from __future__ import annotations
 
 import jax
 
+from repro import spans
 from repro.core.blocking import BlockPlan
 from repro.core.stencil import StencilSpec
 from repro.kernels import ref as _ref
@@ -257,14 +263,16 @@ def stencil_sweep(x: jax.Array, spec: StencilSpec, bx: int | None = None,
                 "'interpret', or the gpu backend on one device.")
         from repro.distributed import halo
         _count_dispatch()
-        return halo.stencil_run_sharded(
-            x, spec, bt, n_devices=nd, bx=bx, bt=bt, variant=variant,
-            backend=backend, source=source, aux=aux, scalars=scalars,
-            devices=devices, overlap=overlap)
+        with spans.span("ops.sweep", bt=bt):
+            return halo.stencil_run_sharded(
+                x, spec, bt, n_devices=nd, bx=bx, bt=bt, variant=variant,
+                backend=backend, source=source, aux=aux, scalars=scalars,
+                devices=devices, overlap=overlap)
     fn = _stencil2d if spec.dims == 2 else _stencil3d
     _count_dispatch()
-    return fn(x, spec, bx=bx, bt=bt, variant=variant, backend=backend,
-              source=source, aux=aux, scalars=scalars)
+    with spans.span("ops.sweep", bt=bt):
+        return fn(x, spec, bx=bx, bt=bt, variant=variant, backend=backend,
+                  source=source, aux=aux, scalars=scalars)
 
 
 def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
@@ -312,23 +320,27 @@ def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
     runner (pipeline used, fallback reason, tiles — see
     ``outofcore.stencil_run_outofcore``) if the run routes there.
     """
-    backend = _resolve(backend)
-    nd = 1 if n_devices is None else n_devices
-    B = _validate_batch(x, spec, aux, scalars, source)
-    bx, bt, variant = resolve_blocking(
-        x, spec, bx, bt, variant, backend, n_steps=n_steps,
-        n_devices=nd, hbm_budget=hbm_budget,
-        extra_streams=int(source is not None), pipeline=pipeline)
-    bt = min(bt, n_steps) if n_steps else bt
-    if backend != "reference":
-        from repro.outofcore import route_decision
-        grid = x.shape[1:] if B is not None else x.shape
-        # Per-device comparison: a sharded run holds ~1/nd of the
-        # working set per device, so a grid that overflows one device
-        # but fits nd shards keeps its in-core deep-halo path.
-        routed, budget = route_decision(
-            spec, grid, x.dtype.itemsize, hbm_budget, batch=B or 1,
-            extra_streams=int(source is not None), n_devices=nd, bt=bt)
+    with spans.span("ops.stencil_run", shape=x.shape, n_steps=n_steps):
+        backend = _resolve(backend)
+        nd = 1 if n_devices is None else n_devices
+        B = _validate_batch(x, spec, aux, scalars, source)
+        with spans.span("ops.plan"):
+            bx, bt, variant = resolve_blocking(
+                x, spec, bx, bt, variant, backend, n_steps=n_steps,
+                n_devices=nd, hbm_budget=hbm_budget,
+                extra_streams=int(source is not None), pipeline=pipeline)
+            bt = min(bt, n_steps) if n_steps else bt
+            routed = False
+            if backend != "reference":
+                from repro.outofcore import route_decision
+                grid = x.shape[1:] if B is not None else x.shape
+                # Per-device comparison: a sharded run holds ~1/nd of the
+                # working set per device, so a grid that overflows one
+                # device but fits nd shards keeps its in-core deep-halo path.
+                routed, budget = route_decision(
+                    spec, grid, x.dtype.itemsize, hbm_budget, batch=B or 1,
+                    extra_streams=int(source is not None), n_devices=nd,
+                    bt=bt)
         if routed:
             # nd > 1 composes: each device streams its own slab's
             # tiles, halos exchanged at tile granularity
@@ -342,41 +354,43 @@ def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
                 source=source, aux=aux, scalars=scalars,
                 pipeline=pipeline, n_devices=nd, devices=devices,
                 metrics=metrics)
-    if scalars is not None:
-        import jax.numpy as jnp
-        scalars = jnp.asarray(scalars, jnp.float32)
-        if B is not None and scalars.ndim == 3:
-            scalars = scalars.reshape(B, n_steps, -1)
-        else:
-            scalars = scalars.reshape(n_steps, -1)
-    if nd > 1 and backend != "reference":
-        if backend == "gpu":
-            raise NotImplementedError(
-                "the deep-halo sharded runner is not wired to the 'gpu' "
-                "backend yet: shard_map + Triton-lowered pallas_call is "
-                "untested here. Run the sharded path on 'pallas' or "
-                "'interpret', or the gpu backend on one device.")
-        from repro.distributed import halo
+        if scalars is not None:
+            import jax.numpy as jnp
+            scalars = jnp.asarray(scalars, jnp.float32)
+            if B is not None and scalars.ndim == 3:
+                scalars = scalars.reshape(B, n_steps, -1)
+            else:
+                scalars = scalars.reshape(n_steps, -1)
+        if nd > 1 and backend != "reference":
+            if backend == "gpu":
+                raise NotImplementedError(
+                    "the deep-halo sharded runner is not wired to the 'gpu' "
+                    "backend yet: shard_map + Triton-lowered pallas_call is "
+                    "untested here. Run the sharded path on 'pallas' or "
+                    "'interpret', or the gpu backend on one device.")
+            from repro.distributed import halo
+            full, rem = divmod(n_steps, bt)
+            _count_dispatch(full + (1 if rem else 0))
+            with spans.span("ops.sweep", bt=bt):
+                return halo.stencil_run_sharded(
+                    x, spec, n_steps, n_devices=nd, bx=bx, bt=bt,
+                    variant=variant, backend=backend, source=source,
+                    aux=aux, scalars=scalars, devices=devices,
+                    overlap=overlap)
         full, rem = divmod(n_steps, bt)
-        _count_dispatch(full + (1 if rem else 0))
-        return halo.stencil_run_sharded(
-            x, spec, n_steps, n_devices=nd, bx=bx, bt=bt, variant=variant,
-            backend=backend, source=source, aux=aux,
-            scalars=scalars, devices=devices, overlap=overlap)
-    full, rem = divmod(n_steps, bt)
-    done = 0
-    for _ in range(full):
-        x = stencil_sweep(x, spec, bx=bx, bt=bt, backend=backend,
-                          variant=variant, source=source, aux=aux,
-                          scalars=(_tslice(scalars, done, done + bt)
-                                   if scalars is not None else None))
-        done += bt
-    if rem:
-        x = stencil_sweep(x, spec, bx=bx, bt=rem, backend=backend,
-                          variant=variant, source=source, aux=aux,
-                          scalars=(_tslice(scalars, done, done + rem)
-                                   if scalars is not None else None))
-    return x
+        done = 0
+        for _ in range(full):
+            x = stencil_sweep(x, spec, bx=bx, bt=bt, backend=backend,
+                              variant=variant, source=source, aux=aux,
+                              scalars=(_tslice(scalars, done, done + bt)
+                                       if scalars is not None else None))
+            done += bt
+        if rem:
+            x = stencil_sweep(x, spec, bx=bx, bt=rem, backend=backend,
+                              variant=variant, source=source, aux=aux,
+                              scalars=(_tslice(scalars, done, done + rem)
+                                       if scalars is not None else None))
+        return x
 
 
 def stencil_program_run(x_or_fields, program, n_steps: int, *,

@@ -37,13 +37,21 @@ Lifecycle (``docs/serving.md`` has the full walk-through):
      served result never differs from the unbatched one. ``check=True``
      re-verifies that per request, for smoke tests.
 
-``metrics`` tracks dispatches, served/padding problem counts, failed
-requests, buckets that failed and were re-served solo
+``metrics`` counts dispatches, served/padding problem counts, failed
+requests, and buckets that failed and were re-served solo
 (``bucket_failures`` — even when every solo retry succeeds, so a
-refused batched kernel is visible), and the measured device-busy
-fraction (time with work in
-flight / wall time) — the quantity batching exists to raise;
-``benchmarks/serving.py`` turns it into a throughput suite.
+refused batched kernel is visible).
+
+**Spans** (``repro.spans``, recorded only under a profiler session, on
+the profiler's clock): ``service.flush`` covers a flush (stats
+``requests``, ``buckets``); inside it ``service.group`` the grouping,
+and per bucket ``service.stack`` the host stacking with padding,
+``service.dispatch`` the dispatcher call (upload, enqueue, a compile on
+a miss; stats ``grid``, ``bucket``, ``pad``, ``uids``),
+``service.device_wait`` the wait for the bucket's result and
+``service.to_host`` its copy to the host and unstacking. The
+per-request fallback runs under ``service.solo``. How long the device
+is idle, and what the host did meanwhile, comes from the trace.
 
 **Error isolation**: a request whose dispatch raises — a mis-shaped
 aux grid that joined a bucket (the key hashes aux *names*), a value
@@ -55,13 +63,13 @@ result, and ``metrics["failed"]`` counts the casualties.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.stencil import StencilProgram, StencilSpec
 from repro.kernels import ops
 
@@ -166,8 +174,7 @@ class StencilService:
         self._outofcore: set = set()
         self.metrics = {"dispatches": 0, "problems": 0, "pad_rows": 0,
                         "outofcore_dispatches": 0, "failed": 0,
-                        "bucket_failures": 0, "busy_s": 0.0,
-                        "wall_s": 0.0}
+                        "bucket_failures": 0}
 
     # ------------------------------------------------------------------
     def submit(self, req: StencilRequest) -> None:
@@ -315,8 +322,9 @@ class StencilService:
                                              self._blocking)
         for r in chunk:
             try:
-                res = np.asarray(jax.block_until_ready(
-                    self._solo_run(r, bx, bt, variant)))
+                with spans.span("service.solo", uid=r.uid):
+                    res = np.asarray(jax.block_until_ready(
+                        self._solo_run(r, bx, bt, variant)))
             except Exception as e:   # noqa: BLE001 — client data is
                 # arbitrary; any per-request failure must stay local.
                 self.metrics["failed"] += 1
@@ -332,106 +340,122 @@ class StencilService:
 
     # ------------------------------------------------------------------
     def flush(self) -> List[StencilCompletion]:
-        t0 = time.perf_counter()
-        # Group by compilation key, preserving arrival order within a
-        # group (continuous admission: a group keeps filling its
-        # current bucket until the queue runs dry or the bucket is
-        # full, exactly like slots absorbing queued requests).
-        groups: dict = {}
-        for r in self._queue:
-            groups.setdefault(self._key(r), []).append(r)
-        self._queue.clear()
+        with spans.span("service.flush",
+                        requests=len(self._queue)) as flush_span:
+            # Group by compilation key, preserving arrival order within
+            # a group (continuous admission: a group keeps filling its
+            # current bucket until the queue runs dry or the bucket is
+            # full, exactly like slots absorbing queued requests).
+            with spans.span("service.group"):
+                groups: dict = {}
+                for r in self._queue:
+                    groups.setdefault(self._key(r), []).append(r)
+                self._queue.clear()
 
-        done: List[StencilCompletion] = []
-        in_flight = []       # (key, reqs, bucket, pad, result_future)
-        t_busy0 = None
-        for key, reqs in groups.items():
-            for i in range(0, len(reqs), self.max_batch):
-                chunk = reqs[i: i + self.max_batch]
-                bucket = bucket_size(len(chunk), self.max_batch)
-                pad = bucket - len(chunk)
-                if t_busy0 is None:
-                    t_busy0 = time.perf_counter()
-                try:
-                    # Stack on the *host* (one memcpy + one device
-                    # upload): jnp.stack over many small device buffers
-                    # costs more than the batched dispatch it feeds.
-                    xb = np.stack(
-                        [np.asarray(r.x, np.dtype(key[2]))
-                         for r in chunk]
-                        + [np.zeros(key[1], np.dtype(key[2]))] * pad)
-                    aux_b = None
-                    if chunk[0].aux:
-                        aux_b = {
-                            nm: np.stack(
-                                [np.asarray(r.aux[nm], xb.dtype)
-                                 for r in chunk]
-                                + [np.zeros(key[1], xb.dtype)] * pad)
-                            for nm in chunk[0].aux}
-                    scal_b = None
-                    if chunk[0].scalars is not None:
-                        scal_b = np.stack(
-                            [np.asarray(r.scalars, np.float32).reshape(
-                                r.n_steps, -1) for r in chunk]
-                            + [np.zeros(
-                                (chunk[0].n_steps,
-                                 chunk[0].spec.n_scalars),
-                                np.float32)] * pad)
-                    out = self._dispatcher(key, bucket)(xb, aux_b,
-                                                        scal_b)
-                except Exception:   # noqa: BLE001 — one bad request
-                    # (mis-shaped aux, poisonous value) must not sink
-                    # its bucket-mates: re-dispatch each one alone.
-                    self.metrics["bucket_failures"] += 1
-                    done.extend(self._serve_solo(key, chunk, bucket))
-                    continue
-                in_flight.append((key, chunk, bucket, pad, out))
-                self.metrics["dispatches"] += 1
-                if (key, bucket) in self._outofcore:
-                    self.metrics["outofcore_dispatches"] += 1
-                self.metrics["pad_rows"] += pad
+            done: List[StencilCompletion] = []
+            in_flight = []       # (key, reqs, bucket, pad, result_future)
+            n_buckets = 0
+            for key, reqs in groups.items():
+                for i in range(0, len(reqs), self.max_batch):
+                    chunk = reqs[i: i + self.max_batch]
+                    n_buckets += 1
+                    out = self._dispatch(key, chunk, done)
+                    if out is not None:
+                        in_flight.append(out)
+            flush_span.set(buckets=n_buckets)
 
-        for key, chunk, bucket, pad, out in in_flight:
-            # One device->host materialization per bucket; slicing the
-            # device array per request would instead dispatch one lazy
-            # gather per request — quietly re-creating the per-problem
-            # dispatch storm the batching removed.
-            try:
-                out = np.asarray(jax.block_until_ready(out))
-            except Exception:   # noqa: BLE001 — async dispatch: a
-                # compiled bucket's failure surfaces here, at readback.
-                self.metrics["bucket_failures"] += 1
-                done.extend(self._serve_solo(key, chunk, bucket))
-                continue
-            for j, r in enumerate(chunk):
-                res = out[j]
-                if self.check:
-                    bx, bt, variant = self._resolved[(key, bucket)]
-                    if r.program is not None:
-                        solo = ops.stencil_program_run(
-                            jnp.asarray(r.x), r.program, r.n_steps,
-                            bx=bx, bt=bt, variant=variant,
-                            backend=self.backend, inputs=r.aux)
-                    else:
-                        solo = ops.stencil_run(
-                            jnp.asarray(r.x), r.spec, r.n_steps, bx=bx,
-                            bt=bt, variant=variant, backend=self.backend,
-                            aux=r.aux, scalars=r.scalars)
-                    np.testing.assert_array_equal(
-                        np.asarray(res), np.asarray(solo),
-                        err_msg=f"served result for request {r.uid} "
-                                f"diverged from its solo run")
-                done.append(StencilCompletion(uid=r.uid, result=res,
-                                              bucket=bucket, padded=pad))
-            self.metrics["problems"] += len(chunk)
-        t1 = time.perf_counter()
-        if t_busy0 is not None:
-            self.metrics["busy_s"] += t1 - t_busy0
-        self.metrics["wall_s"] += t1 - t0
-        return done
+            for key, chunk, bucket, pad, out in in_flight:
+                self._complete(key, chunk, bucket, pad, out, done)
+            return done
 
-    @property
-    def device_busy_fraction(self) -> float:
-        """Measured fraction of service wall time with work in flight."""
-        w = self.metrics["wall_s"]
-        return 0.0 if w == 0 else self.metrics["busy_s"] / w
+    def _dispatch(self, key, chunk, done: List[StencilCompletion]):
+        """Stack one bucket on the host and dispatch it: the in-flight
+        ``(key, chunk, bucket, pad, result)``, or None when it failed
+        and its requests were served solo into ``done``."""
+        bucket = bucket_size(len(chunk), self.max_batch)
+        pad = bucket - len(chunk)
+        try:
+            with spans.span("service.stack"):
+                # Stack on the *host* (one memcpy + one device upload):
+                # jnp.stack over many small device buffers costs more
+                # than the batched dispatch it feeds.
+                xb = np.stack(
+                    [np.asarray(r.x, np.dtype(key[2])) for r in chunk]
+                    + [np.zeros(key[1], np.dtype(key[2]))] * pad)
+                aux_b = None
+                if chunk[0].aux:
+                    aux_b = {
+                        nm: np.stack(
+                            [np.asarray(r.aux[nm], xb.dtype)
+                             for r in chunk]
+                            + [np.zeros(key[1], xb.dtype)] * pad)
+                        for nm in chunk[0].aux}
+                scal_b = None
+                if chunk[0].scalars is not None:
+                    scal_b = np.stack(
+                        [np.asarray(r.scalars, np.float32).reshape(
+                            r.n_steps, -1) for r in chunk]
+                        + [np.zeros(
+                            (chunk[0].n_steps, chunk[0].spec.n_scalars),
+                            np.float32)] * pad)
+            with spans.span("service.dispatch", grid=key[1],
+                            bucket=bucket, pad=pad,
+                            uids=lambda: [r.uid for r in chunk]):
+                out = self._dispatcher(key, bucket)(xb, aux_b, scal_b)
+        except Exception:   # noqa: BLE001 — one bad request (mis-shaped
+            # aux, poisonous value) must not sink its bucket-mates:
+            # re-dispatch each one alone.
+            self.metrics["bucket_failures"] += 1
+            done.extend(self._serve_solo(key, chunk, bucket))
+            return None
+        self.metrics["dispatches"] += 1
+        if (key, bucket) in self._outofcore:
+            self.metrics["outofcore_dispatches"] += 1
+        self.metrics["pad_rows"] += pad
+        return key, chunk, bucket, pad, out
+
+    def _complete(self, key, chunk, bucket: int, pad: int, out,
+                  done: List[StencilCompletion]) -> None:
+        """Read one dispatched bucket back and unstack it into
+        ``done``."""
+        # One device->host materialization per bucket; slicing the
+        # device array per request would instead dispatch one lazy
+        # gather per request — quietly re-creating the per-problem
+        # dispatch storm the batching removed.
+        try:
+            with spans.span("service.device_wait", bucket=bucket):
+                out = jax.block_until_ready(out)
+            with spans.span("service.to_host", bucket=bucket):
+                out = np.asarray(out)
+                served = [StencilCompletion(uid=r.uid, result=out[j],
+                                            bucket=bucket, padded=pad)
+                          for j, r in enumerate(chunk)]
+        except Exception:   # noqa: BLE001 — async dispatch: a compiled
+            # bucket's failure surfaces here, at readback.
+            self.metrics["bucket_failures"] += 1
+            done.extend(self._serve_solo(key, chunk, bucket))
+            return
+        if self.check:
+            for r, c in zip(chunk, served):
+                self._check_solo(key, bucket, r, c.result)
+        done.extend(served)
+        self.metrics["problems"] += len(chunk)
+
+    def _check_solo(self, key, bucket: int, r: StencilRequest,
+                    res) -> None:
+        """``check=True``: the served row equals the request's solo run
+        with the bucket's blocking, bit for bit."""
+        bx, bt, variant = self._resolved[(key, bucket)]
+        if r.program is not None:
+            solo = ops.stencil_program_run(
+                jnp.asarray(r.x), r.program, r.n_steps, bx=bx, bt=bt,
+                variant=variant, backend=self.backend, inputs=r.aux)
+        else:
+            solo = ops.stencil_run(
+                jnp.asarray(r.x), r.spec, r.n_steps, bx=bx, bt=bt,
+                variant=variant, backend=self.backend, aux=r.aux,
+                scalars=r.scalars)
+        np.testing.assert_array_equal(
+            np.asarray(res), np.asarray(solo),
+            err_msg=f"served result for request {r.uid} diverged from "
+                    f"its solo run")

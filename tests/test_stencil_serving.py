@@ -5,6 +5,8 @@ result equals the request's solo run bitwise (batching, bucketing and
 padding are invisible to clients), compilation is bounded by bucketing,
 and completions map back to the right uids in any arrival order.
 """
+import json
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -140,13 +142,42 @@ def test_service_rejects_pre_batched_requests():
         StencilService(max_batch=0)
 
 
-def test_service_metrics_and_busy_fraction():
-    reqs = _mixed_workload(6)
+def test_service_metrics_and_busy_fraction(traced):
+    """The counters, and the flush's span tree: the service keeps no
+    host-clock busy fraction; under a profiler a flush records one
+    ``service.flush`` with grouping and, per bucket, stack, dispatch,
+    device wait and to-host nested inside it."""
+    reqs = _mixed_workload(6)        # three groups of two
     svc = StencilService(max_batch=4, backend="interpret", bx=128, bt=2)
-    svc.run(reqs)
-    assert svc.metrics["problems"] == 6
-    assert 0.0 < svc.device_busy_fraction <= 1.0
-    assert svc.metrics["wall_s"] >= svc.metrics["busy_s"] > 0.0
+    svc.run(_mixed_workload(6))      # compiles outside the trace
+    m0 = dict(svc.metrics)
+    done, snap, events = traced(lambda: svc.run(reqs))
+    assert len(done) == 6
+    d = {k: svc.metrics[k] - m0[k] for k in m0}
+    assert d == {"dispatches": 3, "problems": 6, "pad_rows": 0,
+                 "outofcore_dispatches": 0, "failed": 0,
+                 "bucket_failures": 0}
+    assert not hasattr(svc, "device_busy_fraction")
+    counts = {k: v["count"] for k, v in snap.items()}
+    assert counts == {"service.flush": 1, "service.group": 1,
+                      "service.stack": 3, "service.dispatch": 3,
+                      "service.device_wait": 3, "service.to_host": 3}
+    (flush,) = [e for e in events if e[0] == "service.flush"]
+    assert flush[3]["requests"] == 6 and flush[3]["buckets"] == 3
+    for name, a, b, _ in events:
+        assert flush[1] <= a <= b <= flush[2], name
+    uids = sorted(u for e in events if e[0] == "service.dispatch"
+                  for u in json.loads(e[3]["uids"]))
+    assert uids == list(range(6))
+    # Each bucket is stacked before its dispatch, and read back after.
+    order = [e[0] for e in events if e[0] != "service.flush"]
+    assert order == (["service.group"]
+                     + ["service.stack", "service.dispatch"] * 3
+                     + ["service.device_wait", "service.to_host"] * 3)
+    f = snap["service.flush"]
+    kids = sum(v["total_ns"] for k, v in snap.items()
+               if k != "service.flush")
+    assert f["self_ns"] == f["total_ns"] - kids > 0
 
 
 def test_service_autotuned_blocking_resolves_per_group():
