@@ -182,14 +182,69 @@ class BlockPlan:
 
 
 # ---------------------------------------------------------------------------
-# VMEM model. Mosaic keeps every full-height value of a kernel body in
-# VMEM, so a kernel's footprint is its pipelined blocks and scratch plus
-# a number of live window-sized values that grows with the tap count.
+# Register strips of the 2D revolving kernel. Its fused steps run on a
+# strip of ``S`` output rows at a time, loaded with ``row_halo`` extra
+# rows above and below, on a window of whole lane tiles: ``lane_halo``
+# columns either side of the output tile. ``S`` is sized so that one
+# strip-sized value fills at most the 64-vreg register file.
+# ---------------------------------------------------------------------------
+
+_STRIP_VREGS = 64
+
+
+def row_halo(halo: int, itemsize: int = 4) -> int:
+    """Rows loaded above and below a strip: the fused halo rounded up to
+    the sublane tile."""
+    return round_up(halo, _SUBLANE[itemsize])
+
+
+def lane_halo(halo: int) -> int:
+    """Columns computed either side of an output tile: the fused halo
+    rounded up to whole lane tiles."""
+    return round_up(halo, _LANE)
+
+
+def strip_rows(bx: int, halo: int, rows: int, itemsize: int = 4) -> int:
+    """Output rows per strip of the 2D revolving kernel.
+
+    The largest multiple of the sublane tile whose loaded strip
+    (``S + 2 * row_halo`` rows of ``bx + 2 * lane_halo`` lanes) fits
+    ``_STRIP_VREGS`` vector registers, but at least ``2 * row_halo``
+    (so the rows loaded twice never outnumber the rows kept) and at
+    most the panel's ``rows`` (a panel shorter than one strip runs as
+    one strip).
+    """
+    sub = _SUBLANE[itemsize]
+    hr = row_halo(halo, itemsize)
+    tiles = (bx + 2 * lane_halo(halo)) // _LANE
+    fit = _STRIP_VREGS * sub // tiles - 2 * hr
+    return min(max(fit - fit % sub, 2 * hr, sub), round_up(rows, sub))
+
+
+def edge_strips(rows: int, strip: int, halo: int, lo: int, hi: int,
+                itemsize: int = 4) -> int:
+    """Strips of a ``rows``-row panel whose loaded rows reach outside
+    the valid rows ``[lo, hi)``: the strips of a tile inside the grid's
+    columns that take the kernel's boundary path."""
+    hr = row_halo(halo, itemsize)
+    starts = (min(j * strip, rows - strip)
+              for j in range(-(-rows // strip)))
+    return sum(s - hr < lo or s + strip + hr > hi for s in starts)
+
+
+# ---------------------------------------------------------------------------
+# VMEM model. Mosaic keeps every value of a kernel body that outgrows
+# the registers in VMEM, so a kernel's footprint is its pipelined blocks
+# and scratch plus a number of live window-sized values that grows with
+# the tap count. The 2D revolving kernel's values are strip-sized, and
+# its blocks and scratch hold all of its full-height VMEM; the other
+# kernels' values are full-height.
 # The coefficients below are fitted (rounded up) to the smallest
 # ``vmem_limit_bytes`` at which each kernel compiles for a TPU v5e,
 # found by bisection at 1024 rows and checked linear in rows up to
-# 8192; tests/test_tpu_compile.py holds the model to within
-# ``VMEM_MODEL_MARGIN`` of the compiler on both sides.
+# 8192 (16384 for the revolving 2D kernel); tests/test_tpu_compile.py
+# holds the model to within ``VMEM_MODEL_MARGIN`` of the compiler on
+# both sides.
 # ---------------------------------------------------------------------------
 
 VMEM_MODEL_MARGIN = 0.10
@@ -204,13 +259,25 @@ def _taps(spec: StencilSpec) -> int:
 
 
 def _window_values(spec: StencilSpec, bt: int, n_streams: int) -> float:
-    """Live window-sized values of one fused step (2D: per row panel;
-    3D: per plane)."""
+    """Live window-sized values of one fused step (2D: per window, a
+    row strip in the revolving kernel; 3D: per plane)."""
     if spec.dims == 2:
         return (2.5 + _taps(spec) / 2 + (bt > 1)
                 + 2 * (spec.boundary == "clamp") + (n_streams - 1))
     return (2 * spec.radius + 2) * _taps(spec) / (6 * spec.radius + 1) \
         + 2 * (n_streams - 1)
+
+
+def _panel_vmem_bytes(spec: StencilSpec, *, rows: int, bx: int, bt: int,
+                      halo: int, n_streams: int, blocks: int,
+                      itemsize: int) -> int:
+    """VMEM of a 2D kernel whose window values are full-height: the
+    ``blocks`` lanes of full-height blocks and scratch, plus the live
+    window values."""
+    col = round_up(rows, _SUBLANE[itemsize]) * itemsize   # bytes per lane
+    win = round_up(bx + 2 * halo, _LANE)
+    values = _window_values(spec, bt, n_streams) * win
+    return int(col * (blocks + values))
 
 
 def kernel_vmem_bytes(spec: StencilSpec, *, rows: int, bx: int, bt: int,
@@ -223,18 +290,31 @@ def kernel_vmem_bytes(spec: StencilSpec, *, rows: int, bx: int, bt: int,
     + aux streams). Counted: every BlockSpec block double-buffered (three
     neighbour blocks per stream for the multioperand and 3D kernels, one
     for revolving) and the output block; the revolving ``3*bx`` scratch
-    per stream; the 3D stage windows and source ring; and the live
-    window values (``_window_values``), all lane-padded.
+    per stream, ``2 * row_halo`` rows taller than the panel; the 3D
+    stage windows and source ring; and the live window values
+    (``_window_values``), all lane-padded: full-height for the
+    multioperand and 3D kernels, strip-sized (``strip_rows``) for the
+    revolving one.
     """
+    if spec.dims == 2 and variant == "revolving":
+        col = round_up(rows, _SUBLANE[itemsize]) * itemsize
+        hr = row_halo(halo, itemsize)
+        strip = strip_rows(bx, halo, rows, itemsize)
+        lanes = bx + 2 * lane_halo(halo)
+        # Double-buffered input blocks, the output block, and one
+        # full-height tile: the masked stream-in.
+        blocks = col * (n_streams * 2 * bx + 2 * bx + bx)
+        scratch = n_streams * 3 * bx * (col + 2 * hr * itemsize)
+        values = (_window_values(spec, bt, n_streams) * lanes
+                  * (strip + 2 * hr) * itemsize)
+        return int(blocks + scratch + values)
+    if spec.dims == 2:
+        return _panel_vmem_bytes(
+            spec, rows=rows, bx=bx, bt=bt, halo=halo, n_streams=n_streams,
+            blocks=n_streams * 3 * 2 * bx + 2 * bx, itemsize=itemsize)
     col = round_up(rows, _SUBLANE[itemsize]) * itemsize   # bytes per lane
     win = round_up(bx + 2 * halo, _LANE)
     values = _window_values(spec, bt, n_streams) * win
-    if spec.dims == 2:
-        if variant == "revolving":
-            blocks = n_streams * (2 * bx + 3 * bx)
-        else:
-            blocks = n_streams * 3 * 2 * bx
-        return int(col * (blocks + 2 * bx + values))
     stages = bt * (2 * spec.radius + 1) * win
     ring = (halo + 1) * win * (n_streams > 1)
     blocks = n_streams * 3 * 2 * bx + 2 * bx
@@ -247,8 +327,8 @@ def persistent_vmem_bytes(spec: StencilSpec, slab_shape: Tuple[int, ...],
     """VMEM of the persistent out-of-core kernel
     (``engine.stencil_call_persistent``): two DMA slabs of ``tile +
     2*ghost`` leading rows and one result slab, each ``slab_shape`` (the
-    non-leading, lane-padded dims), plus one x tile's window values (and
-    the 3D stage windows)."""
+    non-leading, lane-padded dims), plus one x tile's full-height window
+    values (and the 3D stage windows)."""
     g = spec.halo(bt)
     align = _SUBLANE[itemsize] if spec.dims == 2 else 1
     rows = round_up(tile, align) + 2 * round_up(g, align)
@@ -257,10 +337,15 @@ def persistent_vmem_bytes(spec: StencilSpec, slab_shape: Tuple[int, ...],
         per_row *= s
     wp = round_up(slab_shape[-1], bx)
     slabs = 3 * rows * per_row * wp
-    panel = rows if spec.dims == 2 else slab_shape[0]
-    tile_vmem = kernel_vmem_bytes(spec, rows=panel, bx=bx, bt=bt, halo=g,
-                                  n_streams=1, variant="revolving",
-                                  itemsize=itemsize)
+    if spec.dims == 2:
+        tile_vmem = _panel_vmem_bytes(spec, rows=rows, bx=bx, bt=bt,
+                                      halo=g, n_streams=1, blocks=7 * bx,
+                                      itemsize=itemsize)
+    else:
+        tile_vmem = kernel_vmem_bytes(spec, rows=slab_shape[0], bx=bx,
+                                      bt=bt, halo=g, n_streams=1,
+                                      variant="revolving",
+                                      itemsize=itemsize)
     return slabs + tile_vmem
 
 

@@ -25,6 +25,14 @@ dimension-*specific* arithmetic is injected as a plugin:
       - ``revolving`` ("advanced", the shift-register analog §3.2.4.1):
         a persistent VMEM scratch holds the last three tiles across the
         sequential grid, so each tile is read from HBM exactly once.
+        In 2D the fused steps then run on **register strips**, the same
+        shift register one level down (VMEM panel → vreg strip): rows
+        ``[s0 - hr, s0 + S + hr)`` of a window of whole lane tiles are
+        loaded, stepped ``bt`` times in registers and cropped to their
+        ``S`` own rows. ``S`` (``core.blocking.strip_rows``) fills the
+        register file with one strip; a strip whose window holds no
+        cell outside the grid skips the boundary fill, which is the
+        identity there (see ``_kernel_2d_revolving``).
         For 3D grids the z axis is *streamed* plane-by-plane through a
         rolling plane window (2.5D blocking) — the same shift-register
         idea along z — so both named variants map to the one streaming
@@ -73,6 +81,7 @@ docs/stencil_ir.md).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 
@@ -82,7 +91,8 @@ import jax.numpy as jnp
 from repro import compat
 from repro.compat import pl, pltpu
 from repro.core.blocking import (_SUBLANE, BlockPlan, kernel_vmem_bytes,
-                                 persistent_vmem_bytes, round_up,
+                                 lane_halo, persistent_vmem_bytes,
+                                 round_up, row_halo, strip_rows,
                                  vmem_limit)
 from repro.core.stencil import StencilSpec
 
@@ -177,7 +187,7 @@ def boundary_fill(win, boundary: str, tile_idx, bx: int, halo: int,
 
 
 def fused_steps(win, specs, bt: int, apply_fns, fills,
-                srcs=None, coeffs=None, scalars=None):
+                srcs=None, coeffs=None, scalars=None, unroll=False):
     """``bt`` fused program-group steps on a window.
 
     ``specs``/``apply_fns``/``fills``/``srcs``/``coeffs``/``scalars``
@@ -191,6 +201,11 @@ def fused_steps(win, specs, bt: int, apply_fns, fills,
     single-sweep execution stays bit-identical; for several stages it
     is bitwise-equal to dispatching the sweeps one at a time, because
     each fill rebuilds out-of-grid cells purely from in-grid cells.
+
+    ``unroll`` traces the ``bt`` steps one after another instead of as
+    a loop, so the compiler can schedule one step's tail under the
+    next step's head: worth it on a register-sized window, where a
+    step is a few hundred bundles.
     """
     M = len(specs)
     if srcs is None:
@@ -209,6 +224,10 @@ def fused_steps(win, specs, bt: int, apply_fns, fills,
                 g = g + srcs[m]
         return g
 
+    if unroll:
+        for t in range(bt):
+            win = body(t, win)
+        return fills[-1](win)
     return fills[-1](jax.lax.fori_loop(0, bt, body, win))
 
 
@@ -296,7 +315,7 @@ def _kernel_2d_multi(*refs, specs, bx, bt, halo, true_w, stages,
 
 
 def _kernel_2d_revolving(*refs, specs, bx, bt, halo, true_w, stages,
-                         apply_fns, batched=False):
+                         apply_fns, strip, batched=False):
     lim_ref, scal_refs, (x_ref,), sgs, cgss, o_ref, it = _unpack_2d(
         refs, stages, 1)
     rd = _reader(batched)
@@ -313,6 +332,10 @@ def _kernel_2d_revolving(*refs, specs, bx, bt, halo, true_w, stages,
     # revolving scratches for every problem — slabs can't leak.
     i = pl.program_id(1 if batched else 0)
     rows = x_ref.shape[-2]
+    # Scratch row hr + y holds grid row y; the hr rows above and below
+    # the panel stay zero and lie outside the grid.
+    hr = row_halo(halo, x_ref.dtype.itemsize)
+    hx = lane_halo(halo)
 
     @pl.when(i == 0)
     def _init():
@@ -332,32 +355,71 @@ def _kernel_2d_revolving(*refs, specs, bx, bt, halo, true_w, stages,
     rr = jax.lax.broadcasted_iota(jnp.int32, (rows, bx), 0)
     inb = (cols < true_w) & (rr >= row_lo) & (rr < row_hi)
     for b, r_in in zip(bufs, streams):
-        b[:, 2 * bx:] = jnp.where(inb, rd(r_in), 0)
+        b[hr: hr + rows, 2 * bx:] = jnp.where(inb, rd(r_in), 0)
 
-    # Compute output tile i-1 from the assembled windows.
-    def window(b):
-        return b[:, bx - halo: 2 * bx + halo]
-
-    def fill_for(boundary):
-        return lambda w: boundary_fill(w, boundary, i - 1, bx, halo,
-                                       true_w, row_lo, row_hi)
-
-    fills = [fill_for(sp.boundary) for sp in specs]
-    bi = iter(bufs)
-    xwin = window(next(bi))
-    srcs, coeffs = [], []
-    for (has_src, meta, _) in stages:
-        srcs.append(fill_for("dirichlet0")(window(next(bi)))
-                    if has_src else None)
-        coeffs.append({name: fill_for(bnd)(window(next(bi)))
-                       for (name, bnd) in meta} or None)
+    # Compute output tile t = i-1, one strip of rows at a time: rows
+    # [s0 - hr, s0 + strip + hr) of the lane-aligned window
+    # [bx - hx, 2*bx + hx) run the fused steps in registers, and the
+    # strip's own rows and the tile's own columns are kept. The window
+    # reaches at least ``halo`` cells past the kept ones on every side,
+    # and past a grid edge it holds out-of-grid cells that every fill
+    # rebuilds, so what a tap reads beyond the window's edge only
+    # reaches the cropped rim: the taps run open (zero-filled), with
+    # no clamp select.
+    t = i - 1
+    cols_inside = (t * bx - hx >= 0) & ((t + 1) * bx + hx <= true_w)
     scals = [rd(sr) if sr is not None else None for sr in scal_refs]
-    win = fused_steps(xwin, specs, bt, apply_fns, fills,
-                      srcs=srcs, coeffs=coeffs, scalars=scals)
-    if batched:
-        o_ref[0] = win[:, halo: halo + bx]
-    else:
-        o_ref[...] = win[:, halo: halo + bx]
+    open_specs = [dataclasses.replace(sp, boundary="dirichlet0")
+                  for sp in specs]
+
+    def run_strip(s0, edge):
+        def window(b):
+            return b[pl.ds(s0, strip + 2 * hr), bx - hx: 2 * bx + hx]
+
+        if edge:
+            # The strip's rows are grid rows from s0 - hr: shift the
+            # validity interval into the strip's coordinates.
+            lo, hi = row_lo - s0 + hr, row_hi - s0 + hr
+
+            def fill_for(boundary):
+                return lambda w: boundary_fill(w, boundary, t, bx, hx,
+                                               true_w, lo, hi)
+        else:
+            # No cell of the window lies outside the grid: every fill
+            # is the identity.
+            def fill_for(boundary):
+                return lambda w: w
+
+        fills = [fill_for(sp.boundary) for sp in specs]
+        bi = iter(bufs)
+        xwin = window(next(bi))
+        srcs, coeffs = [], []
+        for (has_src, meta, _) in stages:
+            srcs.append(fill_for("dirichlet0")(window(next(bi)))
+                        if has_src else None)
+            coeffs.append({name: fill_for(bnd)(window(next(bi)))
+                           for (name, bnd) in meta} or None)
+        win = fused_steps(xwin, open_specs, bt, apply_fns, fills,
+                          srcs=srcs, coeffs=coeffs, scalars=scals,
+                          unroll=True)
+        out = win[hr: hr + strip, hx: hx + bx]
+        if batched:
+            o_ref[0, pl.ds(s0, strip), :] = out
+        else:
+            o_ref[pl.ds(s0, strip), :] = out
+
+    def strip_body(j, carry):
+        # The last strip ends at the panel's last row (it may overlap
+        # the one before, which recomputes the same values).
+        s0 = pl.multiple_of(jnp.minimum(j * strip, rows - strip),
+                            _SUBLANE[x_ref.dtype.itemsize])
+        inside = (cols_inside & (s0 - hr >= row_lo)
+                  & (s0 + strip + hr <= row_hi))
+        pl.when(inside)(lambda: run_strip(s0, edge=False))
+        pl.when(jnp.logical_not(inside))(lambda: run_strip(s0, edge=True))
+        return carry
+
+    jax.lax.fori_loop(0, -(-rows // strip), strip_body, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -556,10 +618,13 @@ def _run_2d(x, specs, plan: BlockPlan, bx, bt, variant, backend, sources,
             name="stencil2d_multioperand",
         )(*(head_args + [a for a in streamed for _ in range(3)]))
     elif variant == "revolving":
-        kern = functools.partial(_kernel_2d_revolving, **kern_kw)
+        kern = functools.partial(
+            _kernel_2d_revolving, **kern_kw,
+            strip=strip_rows(bx, halo, rows, xp.dtype.itemsize))
         in_spec = pl.BlockSpec(block,
                                im(lambda i: (0, jnp.minimum(i, nt - 1))))
-        scratch = [pltpu.VMEM((rows, 3 * bx), xp.dtype)
+        hr = row_halo(halo, xp.dtype.itemsize)
+        scratch = [pltpu.VMEM((rows + 2 * hr, 3 * bx), xp.dtype)
                    for _ in range(n_streamed)]
         out = pl.pallas_call(
             kern,
@@ -1026,9 +1091,11 @@ def stencil_call_program(x: jax.Array, specs, *, bx: int, bt: int,
     ``valid_lo``/``valid_hi``: leading-axis validity interval [lo, hi)
     — rows (2D) / planes (3D) outside it behave as outside the grid
     at every fused step (zero or edge-replicate per each spec's
-    boundary). May be traced scalars; defaults to the full extent.
-    Used by ``distributed/halo.py`` to mark ghost halos and shard
-    padding under one SPMD program.
+    boundary), and the result's rows (planes) outside it are not part
+    of the answer: the 2D revolving kernel leaves zeros in a clamped
+    strip that lies wholly outside. May be traced scalars; defaults to
+    the full extent. Used by ``distributed/halo.py`` to mark ghost halos
+    and shard padding under one SPMD program.
 
     **Batched execution**: ``x`` of rank ``dims + 1`` is a batch of
     ``B`` independent problems sharing one program and grid shape,

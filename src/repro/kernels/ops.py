@@ -56,12 +56,17 @@ Spans (``repro.spans``, recorded only under a profiler session):
 ``ops.stencil_run`` covers a whole run; inside it ``ops.plan`` covers
 the blocking resolution and the out-of-core routing decision, and one
 ``ops.sweep`` per blocked sweep covers the engine call that enqueues it.
+A single-device sweep of the 2D revolving kernel also carries the
+kernel's register-strip geometry: ``strip`` (output rows per strip) and
+``edge_strips`` (strips per tile inside the grid's columns that take
+the boundary path; the rest skip it).
 """
 from __future__ import annotations
 
 import jax
 
 from repro import spans
+from repro.core import blocking
 from repro.core.blocking import BlockPlan
 from repro.core.stencil import StencilSpec
 from repro.kernels import ref as _ref
@@ -229,6 +234,24 @@ def resolve_blocking(x, spec, bx=None, bt=None, variant=None,
 _resolve_blocking = resolve_blocking
 
 
+def _strip_stats(x, spec: StencilSpec, bx: int, bt: int,
+                 variant: str) -> dict:
+    """``ops.sweep`` stats of a sweep that runs the 2D revolving kernel
+    (``core.blocking.strip_rows`` / ``edge_strips``), computed only if
+    the span records."""
+    if spec.dims != 2 or variant != "revolving":
+        return {}
+    h, item, halo = x.shape[-2], x.dtype.itemsize, spec.halo(bt)
+    rows = blocking.round_up(h, blocking._SUBLANE[item])
+
+    def strip():
+        return blocking.strip_rows(bx, halo, rows, item)
+
+    return {"strip": strip,
+            "edge_strips": lambda: blocking.edge_strips(
+                rows, strip(), halo, 0, h, item)}
+
+
 def stencil_sweep(x: jax.Array, spec: StencilSpec, bx: int | None = None,
                   bt: int | None = None, backend: str = "auto",
                   variant: str | None = None,
@@ -270,7 +293,8 @@ def stencil_sweep(x: jax.Array, spec: StencilSpec, bx: int | None = None,
                 devices=devices, overlap=overlap)
     fn = _stencil2d if spec.dims == 2 else _stencil3d
     _count_dispatch()
-    with spans.span("ops.sweep", bt=bt):
+    with spans.span("ops.sweep", bt=bt,
+                    **_strip_stats(x, spec, bx, bt, variant)):
         return fn(x, spec, bx=bx, bt=bt, variant=variant, backend=backend,
                   source=source, aux=aux, scalars=scalars)
 

@@ -22,7 +22,8 @@ TPU mapping (see docs/architecture.md): spatial blocking is 1D in x
 with ``bx``-column tiles and the full y extent VMEM-resident (the
 thesis streams y through a shift register one cell per cycle; the TPU
 VPU wants whole (8,128) tiles, so the engine holds the column panel
-instead); temporal blocking fuses ``bt`` steps per HBM pass, shrinking
+instead, and streams it through the vector registers in row strips);
+temporal blocking fuses ``bt`` steps per HBM pass, shrinking
 validity by ``r`` per step (overlapped blocking, thesis fig. 5-6 a).
 
 Boundary semantics: per ``spec.boundary`` (see docs/stencil_ir.md).

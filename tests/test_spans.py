@@ -221,4 +221,5 @@ def test_profiler_host_plane_nests_program_spans(traced):
     assert by["ops.plan"][2] <= by["ops.sweep"][1]
     assert by["ops.stencil_run"][3] == {"shape": "(16, 128)",
                                         "n_steps": 2}
-    assert by["ops.sweep"][3] == {"bt": 2}
+    # A 16-row panel is one strip, and it reaches past both grid edges.
+    assert by["ops.sweep"][3] == {"bt": 2, "strip": 16, "edge_strips": 1}
