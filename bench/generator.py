@@ -9,7 +9,13 @@ drive the program for the window:
 * ``closed``: one client runs back-to-back ``ops.stencil_run`` solves of
   the configuration's grid, each ending in ``block_until_ready`` as a
   user waiting for a solution does. ``inputs_in_rotation`` distinct
-  inputs take turns, so no solve repeats the one before it.
+  inputs take turns, so no solve repeats the one before it. A cell's
+  ``chips`` reaches it through ``drive``: on more than one chip every
+  input is made split along its leading axis over a 1D mesh of the
+  first ``chips`` devices, so that no device makes or holds a whole
+  grid, and each solve is ``ops.stencil_run(..., n_devices=chips,
+  devices=...)``, the deep-halo sharded runner. On one chip the calls
+  and the inputs are those of a single device.
 * ``open``: requests arrive on a schedule fixed in advance from the
   seed, at ``rate_per_s``, and go through ``StencilService.submit``;
   the loop submits every request that is due, then flushes, and sleeps
@@ -91,17 +97,18 @@ def program_spec(config: dict):
         name=config["name"])
 
 
-def make_problems(config: dict, grid, count: int, key):
+def make_problems(config: dict, grid, count: int, key, sharding=None):
     """``count`` problems of ``grid`` in one jitted call on the device:
     a list of dicts holding the grid ``x`` and every operand the
-    configuration's inputs name, each uniform in its stated range."""
+    configuration's inputs name, each uniform in its stated range.
+    ``sharding`` places every array as it is made, each device making
+    its own part."""
     import jax
     import jax.numpy as jnp
     dtype = jnp.dtype(config["dtype"])
     names = sorted(config["inputs"])
     grid = tuple(grid)
 
-    @jax.jit
     def make(k):
         keys = jax.random.split(k, count * len(names))
         out = []
@@ -115,7 +122,9 @@ def make_problems(config: dict, grid, count: int, key):
             out.append(p)
         return out
 
-    return make(key)
+    if sharding is None:
+        return jax.jit(make)(key)
+    return jax.jit(make, out_shardings=sharding)(key)
 
 
 def _span(annotate: bool, name: str):
@@ -131,7 +140,7 @@ def _plan_fields(tuned) -> dict:
 
 
 def closed(config: dict, traffic: dict, seed: int, seconds: float,
-           annotate: bool, hooks) -> Window:
+           annotate: bool, hooks, chips: int = 1) -> Window:
     """Back-to-back solves by one client (module docstring)."""
     import jax
     from bench import reference
@@ -140,25 +149,40 @@ def closed(config: dict, traffic: dict, seed: int, seconds: float,
     grid = tuple(config["grid"])
     n_steps = int(config["n_steps"])
     k = int(traffic.get("inputs_in_rotation", 1))
-    probs = make_problems(config, grid, k, prng_key(seed))
+    tune_kw, run_kw, sharding = {}, {}, None
+    if chips > 1:
+        devices = jax.devices()[:chips]
+        sharding = jax.sharding.NamedSharding(
+            jax.sharding.Mesh(np.array(devices), ("rows",)),
+            jax.sharding.PartitionSpec("rows"))
+        tune_kw = {"n_devices": chips}
+        run_kw = {"n_devices": chips, "devices": devices}
+    probs = make_problems(config, grid, k, prng_key(seed), sharding)
     src_name = (config["stencil"].get("source") or {}).get("operand")
     auxes = [({src_name: reference.source_grid(config["stencil"], p)}
               if src_name else None) for p in probs]
 
     def solve(i):
         return ops.stencil_run(probs[i % k]["x"], spec, n_steps,
-                               backend="auto", aux=auxes[i % k])
+                               backend="auto", aux=auxes[i % k], **run_kw)
 
     for i in range(k):                       # warm-up: compiles once
         jax.block_until_ready(solve(i))
     tuned = autotune.plan(grid, spec, dtype=config["dtype"],
-                          backend="auto", n_steps=n_steps)
+                          backend="auto", n_steps=n_steps, **tune_kw)
     d0 = ops.dispatch_count()
     n = 0
+    y = None
     hooks.begin()
     t0 = time.perf_counter()
     with _span(annotate, "window"):
         while True:
+            if chips > 1:
+                # The sharded runner holds a padded copy of its input and
+                # three times its output in temporaries a chip: on a grid
+                # sized to fill the chips the last solution cannot live
+                # through the next solve.
+                y = None
             with _span(annotate, "stencil_run"):
                 y = solve(n)
             with _span(annotate, "block_until_ready"):
@@ -173,7 +197,7 @@ def closed(config: dict, traffic: dict, seed: int, seconds: float,
         seconds=t1 - t0, attempted=n, completed=n, failed=0,
         work=[{"grid": grid, "n_steps": n_steps, "count": n}],
         counters={"dispatches": ops.dispatch_count() - d0, "solves": n},
-        plan=_plan_fields(tuned),
+        plan=dict(_plan_fields(tuned), **tune_kw),
         outputs=[{"got": y, "problem": probs[last], "grid": grid,
                   "n_steps": n_steps}])
 
@@ -198,8 +222,11 @@ def schedule(traffic: dict, seed: int, seconds: float):
 
 
 def open_(config: dict, traffic: dict, seed: int, seconds: float,
-          annotate: bool, hooks) -> Window:
-    """Open-loop arrivals through ``StencilService`` (module docstring)."""
+          annotate: bool, hooks, chips: int = 1) -> Window:
+    """Open-loop arrivals through ``StencilService`` (module docstring),
+    on one chip."""
+    if chips != 1:
+        raise ValueError(f"the open loop runs on one chip, not {chips}")
     import jax
     from bench import reference
     from repro.kernels import autotune
@@ -313,12 +340,13 @@ DRIVERS = {"closed": closed, "open": open_}
 
 
 def drive(config: dict, traffic: dict, seed: int, seconds: float,
-          annotate: bool, hooks) -> Window:
-    """Run the driver the mix's ``loop`` names. ``hooks.begin()`` is
-    called when set-up ends and the window starts, ``hooks.end()`` when
-    the window closes."""
+          annotate: bool, hooks, chips: int = 1) -> Window:
+    """Run the driver the mix's ``loop`` names on the first ``chips``
+    devices. ``hooks.begin()`` is called when set-up ends and the window
+    starts, ``hooks.end()`` when the window closes."""
     loop = traffic["loop"]
     if loop not in DRIVERS:
         raise ValueError(f"traffic loop {loop!r} is not one of "
                          f"{sorted(DRIVERS)}")
-    return DRIVERS[loop](config, traffic, seed, seconds, annotate, hooks)
+    return DRIVERS[loop](config, traffic, seed, seconds, annotate, hooks,
+                         chips)

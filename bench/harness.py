@@ -18,6 +18,12 @@ files and new entries alone:
 A ``--trace 0`` run reports the cell's end-to-end metrics, a
 ``--trace 1`` run its per-layer metrics: the whole window is traced by
 the profiler, the trace is reduced by ``bench/tracing.py`` and deleted.
+
+A cell's ``chips`` (1 or 4) is handed to ``generator.drive``, whose
+closed loop then makes its inputs split over that many devices and runs
+the program's sharded route on them (``bench/generator.py``); the
+memory peak is the fullest of those devices, and the readers take the
+count from ``run.cell.chips``.
 """
 from __future__ import annotations
 
@@ -184,7 +190,7 @@ def warm(root: pathlib.Path, name: str, seed: int) -> None:
     tuning and compiling of a checkout's first run."""
     cell = find_cell(pathlib.Path(root), name)
     generator.drive(cell.config, cell.traffic, seed, 0.0, annotate=False,
-                    hooks=generator.NoHooks())
+                    hooks=generator.NoHooks(), chips=cell.chips)
 
 
 def run_cell(root: pathlib.Path, name: str, seed: int, seconds: float,
@@ -198,7 +204,8 @@ def run_cell(root: pathlib.Path, name: str, seed: int, seconds: float,
     hooks = _Hooks(t_start, trace)
     try:
         win = generator.drive(cell.config, cell.traffic, seed, seconds,
-                              annotate=trace, hooks=hooks)
+                              annotate=trace, hooks=hooks,
+                              chips=cell.chips)
         devices = jax.devices()[: cell.chips]
         memory_peak = memory_peak_bytes(devices)
         summary = (tracing.reduce(tracing.load(hooks.trace_dir))

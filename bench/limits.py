@@ -45,12 +45,17 @@ def main(argv=None) -> int:
         print("limits: JAX found no TPU", file=sys.stderr)
         return 1
     cell = harness.find_cell(ROOT, args.workload)
+    if jax.device_count() < cell.chips:
+        print(f"limits: the cell asks for {cell.chips} chips, JAX sees "
+              f"{jax.device_count()}", file=sys.stderr)
+        return 1
     seconds = 0.0 if cell.traffic["loop"] == "closed" else args.seconds
     program, control = [], []
     for i in range(args.seeds):
         seed = args.first_seed + i
         win = generator.drive(cell.config, cell.traffic, seed, seconds,
-                              annotate=False, hooks=generator.NoHooks())
+                              annotate=False, hooks=generator.NoHooks(),
+                              chips=cell.chips)
         line = {"seed": seed, "attempted": win.attempted,
                 "compared": len(win.outputs),
                 "program": check.compare(win, cell.config)}

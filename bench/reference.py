@@ -8,6 +8,14 @@ program made, so a change under ``src/`` cannot move the yardstick.
 
 ``multistep`` runs in the dtype it is given: float32 for the reference
 itself, bfloat16 for the control that the comparison has to fail.
+
+``multistep_rows`` gives a block of leading-axis rows of the same result
+for a grid too large for one device: it runs the same taps on the block
+extended by ``radius * n_steps`` ghost rows on each side that lies
+inside the grid (``ghost``). The block's false boundary corrupts
+``radius`` rows a step from the outside in, so after ``n_steps`` steps
+its error stops short of the kept rows, under either boundary; where the
+block meets the grid's edge, its boundary is the grid's own.
 """
 from __future__ import annotations
 
@@ -81,6 +89,83 @@ def multistep(x, stencil: dict, n_steps: int, source=None):
     if source is not None:
         source = source.astype(x.dtype)
     return _multistep(x, source, _frozen(stencil), n_steps)
+
+
+def step_padded(x, stencil: dict, source=None):
+    """``step`` read from one copy of ``x`` padded by the radius (zeros
+    for ``dirichlet0``, the edge cell for ``clamp``): the same taps added
+    in the same order, in one fused pass that holds one padded grid where
+    ``step`` holds a shifted grid per tap. XLA may fuse the two
+    differently, so they agree to float rounding, not always bit for
+    bit."""
+    r = stencil["radius"]
+    mode = {"clamp": "edge", "dirichlet0": "constant"}[stencil["boundary"]]
+    xp = jnp.pad(x, r, mode=mode)
+    acc = jnp.asarray(stencil["center"], x.dtype) * x
+    for axis, row in enumerate(stencil["axis_weights"]):
+        for o in range(-r, r + 1):
+            w = float(row[r + o])
+            if o == 0 or w == 0.0:
+                continue
+            idx = [slice(r, r + n) for n in x.shape]
+            idx[axis] = slice(r + o, r + o + x.shape[axis])
+            acc = acc + jnp.asarray(w, x.dtype) * xp[tuple(idx)]
+    if source is not None:
+        acc = acc + source
+    return acc
+
+
+@functools.partial(jax.jit, static_argnames=("frozen", "n_steps"),
+                   donate_argnums=0)
+def _multistep_padded(x, source, frozen: tuple, n_steps: int):
+    r, boundary, center, weights = frozen
+    stencil = {"radius": r, "boundary": boundary, "center": center,
+               "axis_weights": weights}
+    return jax.lax.fori_loop(0, n_steps,
+                             lambda _, g: step_padded(g, stencil, source),
+                             x)
+
+
+def ghost(stencil: dict, n_steps: int) -> int:
+    """Rows a block needs beyond each inner edge for ``n_steps`` steps."""
+    return int(stencil["radius"]) * int(n_steps)
+
+
+def rows(x, lo: int, hi: int, device):
+    """Rows ``[lo, hi)`` of ``x``'s leading axis as a new array on
+    ``device`` (never one of ``x``'s own buffers, so that it can be
+    donated), gathered from the shards of ``x`` that hold them."""
+    pieces, seen = [], set()
+    n = x.shape[0]
+    for sh in sorted(x.addressable_shards,
+                     key=lambda sh: (sh.index[0].indices(n)[0],
+                                     sh.device != device)):
+        s0, s1, _ = sh.index[0].indices(n)
+        a, b = max(lo, s0), min(hi, s1)
+        if a >= b or s0 in seen:
+            continue
+        seen.add(s0)
+        pieces.append(jax.device_put(sh.data[a - s0:b - s0], device))
+    if len(pieces) == 1:
+        return jnp.copy(pieces[0])
+    return jnp.concatenate(pieces)
+
+
+def multistep_rows(operands: dict, stencil: dict, n_steps: int, lo: int,
+                   hi: int, device, dtype=jnp.float32):
+    """Rows ``[lo, hi)`` of ``n_steps`` steps of ``stencil`` on
+    ``operands["x"]`` (with the source grid the stencil states), computed
+    in ``dtype`` on ``device`` from the block and its ghost rows alone
+    (module docstring). ``operands`` may be split over devices along the
+    leading axis."""
+    n = operands["x"].shape[0]
+    g = ghost(stencil, n_steps)
+    a, b = max(0, lo - g), min(n, hi + g)
+    block = {k: rows(v, a, b, device) for k, v in operands.items()}
+    src = source_grid(stencil, block, dtype)
+    out = _multistep_padded(block.pop("x").astype(dtype), src,
+                            _frozen(stencil), n_steps)
+    return out[lo - a:hi - a]
 
 
 def source_grid(stencil: dict, operands: dict, dtype=jnp.float32):

@@ -51,3 +51,24 @@ def half_batch(config: dict, real):
         h = x.shape[0] // 2
         return jnp.concatenate([y[:h], jnp.asarray(x)[h:]])
     return run
+
+
+def no_exchange(config: dict, real):
+    """The exchange between chips left out: each device runs the rows of
+    a split grid that it holds alone, as a grid of its own, so no halo
+    crosses from a neighbour. A grid on one device runs as it would."""
+    def run(x, spec, n_steps, aux=None, n_devices=None, devices=None,
+            **kw):
+        if not n_devices or n_devices == 1:
+            return real(x, spec, n_steps, aux=aux, **kw)
+
+        def local(a):
+            return {sh.device: sh.data for sh in a.addressable_shards}
+        auxes = {k: local(v) for k, v in (aux or {}).items()}
+        pieces = [jax.device_put(
+            real(data, spec, n_steps,
+                 aux={k: v[dev] for k, v in auxes.items()} or None, **kw),
+            dev) for dev, data in local(x).items()]
+        return jax.make_array_from_single_device_arrays(x.shape, x.sharding,
+                                                        pieces)
+    return run

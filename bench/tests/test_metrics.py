@@ -12,8 +12,8 @@ HOTSPOT = json.loads((BENCH / "configs" / "hotspot2d.json").read_text())
 KIND = "TPU v5 lite"
 
 
-def _run(window, trace=None, setup_s=12.5):
-    cell = types.SimpleNamespace(config=HOTSPOT)
+def _run(window, trace=None, setup_s=12.5, chips=1):
+    cell = types.SimpleNamespace(config=HOTSPOT, chips=chips)
     return harness.Run(cell=cell, window=window, setup_s=setup_s,
                        device_kind=KIND, trace=trace)
 
@@ -86,3 +86,33 @@ def test_trace_readers_find_nothing_without_a_device_trace(name):
     assert _read(name, _run(_solve_window())) is None
     empty = tracing.Summary(0.0, 0.0, 2.0, [], [])
     assert _read(name, _run(_solve_window(), empty)) is None
+
+
+def test_step_mfu_counts_the_cells_chips():
+    """The least time of the work on four chips is one chip's over
+    four; on one chip the reading is the one-chip formula itself."""
+    trace = tracing.Summary(kernel_s=1.6, busy_s=1.9, window_s=2.0,
+                            device_ops=[], idle_gaps=[])
+    one = _read("step_mfu.solve", _run(_solve_window(), trace))
+    four = _read("step_mfu.solve", _run(_solve_window(), trace, chips=4))
+    assert four == pytest.approx(one / 4)
+    run = _run(_solve_window(), trace)
+    assert one == 100.0 * run.count["roofline_s"] / trace.window_s
+    # The kernels' share is summed over devices already: no chips in it.
+    assert _read("engine_roofline.solve", _run(_solve_window(), trace,
+                                               chips=4)) == \
+        _read("engine_roofline.solve", run)
+
+
+def test_halo_exposed_share():
+    """Collective time summed over the devices, over devices x window."""
+    trace = tracing.Summary(kernel_s=1.6, busy_s=1.9, window_s=2.0,
+                            device_ops=[], idle_gaps=[], collective_s=0.4,
+                            devices=4)
+    run = _run(_solve_window(), trace, chips=4)
+    assert _read("halo_exposed_share.solve", run) == pytest.approx(5.0)
+    for t in (None, tracing.Summary(1.6, 1.9, 2.0, [], []),
+              tracing.Summary(1.6, 1.9, 2.0, [], [], collective_s=0.0,
+                              devices=4)):
+        assert _read("halo_exposed_share.solve",
+                     _run(_solve_window(), t, chips=4)) is None

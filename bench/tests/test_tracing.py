@@ -37,10 +37,11 @@ def test_reduce_hand_computed():
     # kernels inside the window: 30 + 40 (clipped at 100) ms
     assert s.kernel_s == pytest.approx(0.070)
     ops = dict(s.device_ops)
-    assert ops["k.4 f32[8] custom-call"] == pytest.approx(0.040)
+    # k.2 and k.4 are one kernel, called twice: 30 + 40 ms
+    assert ops["k f32[8] custom-call"] == pytest.approx(0.070)
     assert ops["copy.3"] == pytest.approx(0.010)
-    assert [n for n, _ in s.device_ops][:2] == ["k.4 f32[8] custom-call",
-                                                "k.2 f32[8] custom-call"]
+    assert [n for n, _ in s.device_ops] == ["k f32[8] custom-call",
+                                            "copy.3", "fusion.1"]
     gaps = dict(s.idle_gaps)
     assert gaps == pytest.approx({"stencil_run": 0.005,
                                   "block_until_ready": 0.015})
@@ -64,6 +65,52 @@ def test_gap_with_no_span_is_none_and_devices_average():
     assert s.busy_s == pytest.approx((0.080 + 0.100) / 2)
     assert dict(s.idle_gaps) == pytest.approx({"none": 0.010})
     assert s.kernel_s == pytest.approx(0.170)
+
+
+def test_collective_time_on_two_devices():
+    """Collective permutes (``-start``, ``-done``, synchronous) on two
+    device planes: the union of their intervals inside the window on
+    each device, summed over the devices; other ops are not counted,
+    nor a fusion that takes a permute's result as its operand (the edge
+    fusion of the sharded runner)."""
+    cps = ('%collective-permute-start.3 = (f32[4,8]{1,0}, f32[4,8]{1,0}) '
+           'collective-permute-start(f32[4,8]{1,0} %p), '
+           'source_target_pairs={{0,1}}')
+    cpd = ('%collective-permute-done.3 = f32[4,8]{1,0} '
+           'collective-permute-done((f32[4,8]{1,0}, f32[4,8]{1,0}) %s)')
+    cp = '%collective-permute.1 = f32[4,8]{1,0} collective-permute(%p)'
+    k = '%k = f32[8]{0} custom-call(), custom_call_target="tpu_custom_call"'
+    edge = ('%fusion.12 = (f32[12,8]{1,0}, f32[12,8]{1,0}) fusion('
+            'f32[4,8]{1,0} %collective-permute-done.3, f32[8,8]{1,0} %q), '
+            'kind=kLoop, calls=%fused_computation.12')
+    t = {"devices": {
+        "/device:TPU:0": [[cps, 0, 1 * MS], [k, 1 * MS, 40 * MS],
+                          [cpd, 41 * MS, 4 * MS],
+                          [cpd, 98 * MS, 5 * MS]],      # clipped to 2 ms
+        "/device:TPU:1": [[cps, 0, 2 * MS], [k, 2 * MS, 50 * MS],
+                          [cp, 60 * MS, 3 * MS],
+                          ["fusion.9", 70 * MS, 10 * MS],
+                          [edge, 80 * MS, 6 * MS]],
+        "/device:TPU:2": []},
+        "spans": [["window", 0, 100 * MS]]}
+    s = tracing.reduce(t)
+    assert s.collective_s == pytest.approx(0.001 + 0.004 + 0.002
+                                           + 0.002 + 0.003)
+    assert s.devices == 2
+    assert tracing.is_collective(cps) and tracing.is_collective(cpd)
+    assert tracing.is_collective(cp) and not tracing.is_collective(k)
+    assert not tracing.is_collective(edge)
+    assert tracing.is_collective("collective-permute-done.3")
+    assert not tracing.is_collective("fusion.9")
+    from bench.tests.test_metrics import _read, _run, _solve_window
+    share = _read("halo_exposed_share.solve",
+                  _run(_solve_window(), s, chips=2))
+    assert share == pytest.approx(100 * 0.012 / (2 * 0.100))
+
+
+def test_single_chip_trace_has_no_collective_time():
+    s = tracing.reduce(_trace())
+    assert s.collective_s == 0.0 and s.devices == 1
 
 
 def test_empty_trace_reads_nothing():
@@ -117,7 +164,7 @@ def test_recorded_trace(tag):
 def test_recorded_hotspot_trace_is_the_2d_kernel():
     s = tracing.reduce(_recorded()["hotspot"])
     # 3 solves x 8 dispatches of the one 8192^2 kernel, ~16 ms each
-    assert s.device_ops == [["stencil_call_program.1 f32[8192,8192] "
+    assert s.device_ops == [["stencil_call_program f32[8192,8192] "
                              "custom-call", pytest.approx(s.kernel_s)]]
     assert s.kernel_s == pytest.approx(0.386, rel=0.01)
     assert 1 - s.busy_s / s.window_s < 0.02

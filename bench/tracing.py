@@ -12,8 +12,17 @@ a small recorded trace committed beside them.
 * Kernel time is the summed device time of the Mosaic kernels (custom
   calls to ``tpu_custom_call``: the engine's Pallas kernels, which the
   program gives no stable names yet), over all devices.
-* ``device_ops``: the ten device ops with the most time, by instruction
-  name, shape and opcode (``short_name``).
+* Collective time (``collective_s``) is the device time of the ops whose
+  opcode is a collective permute (``collective-permute-start``,
+  ``-done`` or the synchronous one; not an op that only takes a
+  permute's result as an operand), the union of their intervals on each
+  device, summed over the devices. On a TPU core they hold the op line,
+  so it is the exchange that compute did not hide. ``devices`` counts
+  the devices that ran anything in the window.
+* ``device_ops``: the ten device ops with the most time, summed over the
+  devices, by instruction name less its number, shape and opcode
+  (``short_name``): the same kernel or fusion of every sweep of an
+  unrolled program, and of every device, counts as one op.
 * ``idle_gaps``: the window's idle device time, each gap charged to the
   innermost benchmark span open on the host at the gap's midpoint, the
   ten largest totals by span name (``none`` where no span was open).
@@ -38,6 +47,8 @@ class Summary:
     window_s: float
     device_ops: list
     idle_gaps: list
+    collective_s: float = 0.0
+    devices: int = 0
 
 
 def load(trace_dir: str) -> dict:
@@ -76,15 +87,35 @@ def is_kernel(name: str) -> bool:
     return "tpu_custom_call" in name
 
 
-def short_name(name: str) -> str:
-    """An op's HLO text cut to its instruction name, result shape and
-    opcode: ``stencil_call_program.1 f32[8192,8192] custom-call``."""
+def _parts(name: str):
+    """``(instruction name less its number, result shape, opcode)`` of an
+    op's HLO text ``%name.N = shape opcode(operands), ...``; None for an
+    event name that is not HLO text."""
     lhs, sep, rhs = name.partition(" = ")
     m = re.search(r"\s([a-z][\w.\-]*)\(", rhs) if sep else None
     if m is None:
-        return name
+        return None
     shape = re.sub(r"\{[^}]*\}", "", rhs[:m.start()]).replace(" ", "")
-    return f"{lhs.lstrip('%')} {shape} {m.group(1)}"
+    base = re.sub(r"\.\d+$", "", lhs.strip().lstrip("%"))
+    return base, shape, m.group(1)
+
+
+def is_collective(name: str) -> bool:
+    """A halo exchange: an op whose opcode is an asynchronous or
+    synchronous collective permute. A fusion that reads a permute's
+    result (``fusion(%collective-permute-done.3, ...)``) is compute."""
+    parts = _parts(name)
+    op = parts[2] if parts else re.sub(r"\.\d+$", "", name.lstrip("%"))
+    return op.startswith("collective-permute")
+
+
+def short_name(name: str) -> str:
+    """An op's HLO text cut to its instruction name less its number,
+    result shape and opcode: ``stencil3d_stream f32[512,512,512]
+    custom-call`` for ``%stencil3d_stream.193 = f32[512,512,512]{...}
+    custom-call(...)``."""
+    parts = _parts(name)
+    return " ".join(parts) if parts else name
 
 
 def _union(intervals) -> list:
@@ -133,11 +164,13 @@ def reduce(trace: dict) -> Summary:
     else:
         return Summary(0.0, 0.0, 0.0, [], [])
     kernel_ns = 0.0
+    collective_ns = 0.0
     op_ns: dict = {}
     gaps = []
     busy = []
     for evs in trace["devices"].values():
         inside = []
+        collective = []
         for name, s, d in evs:
             a, b = max(s, w0), min(s + d, w1)
             if b <= a:
@@ -147,6 +180,9 @@ def reduce(trace: dict) -> Summary:
             op_ns[short] = op_ns.get(short, 0.0) + (b - a)
             if is_kernel(name):
                 kernel_ns += b - a
+            if is_collective(name):
+                collective.append((a, b))
+        collective_ns += sum(b - a for a, b in _union(collective))
         merged = _union(inside)
         if not merged:
             continue
@@ -160,4 +196,6 @@ def reduce(trace: dict) -> Summary:
         window_s=(w1 - w0) * 1e-9,
         device_ops=_top({k: v * 1e-9 for k, v in op_ns.items()}),
         idle_gaps=_top({k: v / n * 1e-9
-                        for k, v in _charge(spans, gaps).items()}))
+                        for k, v in _charge(spans, gaps).items()}),
+        collective_s=collective_ns * 1e-9,
+        devices=len(busy))
