@@ -23,6 +23,15 @@ _LANE = 128     # TPU lane width
 _SUBLANE = {4: 8, 2: 16}   # sublane count by itemsize
 
 
+def aux_streams(spec: StencilSpec, source: bool = False) -> int:
+    """Operand streams the engine runs alongside the main grid: one per
+    coeff operand, plus one for all source operands together, a
+    caller's ``source=`` grid among them (the engine pre-sums sources
+    into a single additive grid — see engine.stencil_call)."""
+    n_src = sum(op.role == "source" for op in spec.aux)
+    return (len(spec.aux) - n_src) + int(source or n_src > 0)
+
+
 def round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
@@ -130,12 +139,9 @@ class BlockPlan:
 
     @property
     def n_aux(self) -> int:
-        """Operand *streams* the engine runs alongside the main grid:
-        one per coeff operand, plus one for all source operands
-        together (the engine pre-sums sources into a single additive
-        grid — see engine.stencil_call)."""
-        n_src = sum(op.role == "source" for op in self.spec.aux)
-        return (len(self.spec.aux) - n_src) + min(n_src, 1)
+        """Operand *streams* the engine runs alongside the main grid
+        (:func:`aux_streams`)."""
+        return aux_streams(self.spec)
 
     def hbm_bytes_per_sweep(self, read_amplification: float = 1.0) -> float:
         """HBM traffic for one pass: one read of every input operand

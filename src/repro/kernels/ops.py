@@ -56,10 +56,13 @@ Spans (``repro.spans``, recorded only under a profiler session):
 ``ops.stencil_run`` covers a whole run; inside it ``ops.plan`` covers
 the blocking resolution and the out-of-core routing decision, and one
 ``ops.sweep`` per blocked sweep covers the engine call that enqueues it.
-A single-device sweep of the 2D revolving kernel also carries the
-kernel's register-strip geometry: ``strip`` (output rows per strip) and
-``edge_strips`` (strips per tile inside the grid's columns that take
-the boundary path; the rest skip it).
+Each ``ops.sweep`` carries ``bt``, the spec's ``boundary`` and
+``streams`` (the grids the kernel streams: the state, one pre-summed
+source grid if any, one per coeff operand). A single-device sweep of the
+2D revolving kernel also carries the kernel's register-strip geometry:
+``strip`` (output rows per strip) and ``edge_strips`` (strips per tile
+inside the grid's columns that take the boundary path; the rest skip
+it).
 """
 from __future__ import annotations
 
@@ -234,6 +237,14 @@ def resolve_blocking(x, spec, bx=None, bt=None, variant=None,
 _resolve_blocking = resolve_blocking
 
 
+def _sweep_stats(spec: StencilSpec, bt: int, source) -> dict:
+    """``ops.sweep`` stats of any sweep; ``streams`` is computed only if
+    the span records."""
+    return {"bt": bt, "boundary": spec.boundary,
+            "streams": lambda: 1 + blocking.aux_streams(
+                spec, source is not None)}
+
+
 def _strip_stats(x, spec: StencilSpec, bx: int, bt: int,
                  variant: str) -> dict:
     """``ops.sweep`` stats of a sweep that runs the 2D revolving kernel
@@ -286,14 +297,14 @@ def stencil_sweep(x: jax.Array, spec: StencilSpec, bx: int | None = None,
                 "'interpret', or the gpu backend on one device.")
         from repro.distributed import halo
         _count_dispatch()
-        with spans.span("ops.sweep", bt=bt):
+        with spans.span("ops.sweep", **_sweep_stats(spec, bt, source)):
             return halo.stencil_run_sharded(
                 x, spec, bt, n_devices=nd, bx=bx, bt=bt, variant=variant,
                 backend=backend, source=source, aux=aux, scalars=scalars,
                 devices=devices, overlap=overlap)
     fn = _stencil2d if spec.dims == 2 else _stencil3d
     _count_dispatch()
-    with spans.span("ops.sweep", bt=bt,
+    with spans.span("ops.sweep", **_sweep_stats(spec, bt, source),
                     **_strip_stats(x, spec, bx, bt, variant)):
         return fn(x, spec, bx=bx, bt=bt, variant=variant, backend=backend,
                   source=source, aux=aux, scalars=scalars)
@@ -395,7 +406,8 @@ def stencil_run(x: jax.Array, spec: StencilSpec, n_steps: int,
             from repro.distributed import halo
             full, rem = divmod(n_steps, bt)
             _count_dispatch(full + (1 if rem else 0))
-            with spans.span("ops.sweep", bt=bt):
+            with spans.span("ops.sweep",
+                            **_sweep_stats(spec, bt, source)):
                 return halo.stencil_run_sharded(
                     x, spec, n_steps, n_devices=nd, bx=bx, bt=bt,
                     variant=variant, backend=backend, source=source,

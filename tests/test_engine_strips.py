@@ -242,13 +242,34 @@ def test_strip_kernel_matches_its_oracles(case, without_fma):
     assert without_fma[case] is None, without_fma[case]
 
 
-def test_strip_geometry_of_the_hotspot_plans():
-    """8192 rows, radius 1, bt 8: a strip fills the 64 vector registers
-    (3 lane tiles at bx=128, 4 at bx=256), and a tile inside the grid's
-    columns sends its first and last strip down the boundary path."""
+def _hotspot():
+    from repro.apps import hotspot
+    return hotspot.spec_of(hotspot.HotspotParams())
+
+
+@pytest.mark.parametrize("make_spec,boundary", [
+    (_hotspot, "clamp"), (lambda: diffusion(2, 1), "dirichlet0")],
+    ids=["hotspot2d", "diffusion2d_r1"])
+def test_strip_geometry_of_the_hotspot_plans(make_spec, boundary, tmp_path,
+                                             monkeypatch):
+    """8192 rows, radius 1: the model plans ``bx=256 bt=8`` for either
+    boundary, with or without a source grid. A strip fills the 64
+    vector registers (3 lane tiles at bx=128, 4 at bx=256), and a tile
+    inside the grid's columns sends its first and last strip down the
+    boundary path."""
+    from repro.core.perf_model import V5E
+    from repro.kernels import autotune
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "at.json"))
+    spec = make_spec()
+    tuned = autotune.plan((8192, 8192), spec, backend="pallas", tpu=V5E,
+                          n_steps=64, measure=False, use_cache=False)
+    assert (spec.boundary, tuned.bx, tuned.bt, tuned.variant) == \
+        (boundary, 256, 8, "revolving")
+    strip = blocking.strip_rows(tuned.bx, spec.halo(tuned.bt), 8192)
+    assert strip == 112
+    assert blocking.edge_strips(8192, strip, spec.halo(tuned.bt), 0,
+                                8192) == 2
     assert blocking.strip_rows(128, 8, 8192) == 152
-    assert blocking.strip_rows(256, 8, 8192) == 112
-    assert blocking.edge_strips(8192, 112, 8, 0, 8192) == 2
     assert blocking.edge_strips(8192, 152, 8, 0, 8192) == 2
     # A deep halo keeps at least twice its rows per strip.
     assert blocking.strip_rows(1024, 16, 8192) == 32

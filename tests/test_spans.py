@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import spans
-from repro.core.stencil import diffusion
+from repro.core.stencil import AuxOperand, StencilSpec, diffusion
 from repro.kernels import ops
 from repro.serving import StencilRequest, StencilService
 
@@ -142,6 +142,42 @@ def test_stencil_run_records_plan_and_one_sweep_per_dispatch(traced):
         [2, 2, 1] * 2
 
 
+SCALED = StencilSpec(dims=2, radius=1, boundary="clamp",
+                     update=lambda f, sp: f["x"] * f["k"],
+                     aux=(AuxOperand("k", role="coeff"),
+                          AuxOperand("q", role="source")),
+                     name="scaled")
+
+
+@pytest.mark.parametrize("spec,operands,boundary,streams", [
+    (diffusion(2, 1), (), "dirichlet0", 1),
+    (diffusion(2, 1, boundary="clamp"), ("source",), "clamp", 2),
+    (SCALED, ("k", "q"), "clamp", 3),
+    (diffusion(3, 1), (), "dirichlet0", 1),
+], ids=["sourceless", "legacy_source", "coeff_and_source", "3d"])
+def test_sweep_span_names_boundary_and_streams(fake_profiler, monkeypatch,
+                                                spec, operands, boundary,
+                                                streams):
+    """The grids a sweep streams: the state, one grid for all sources,
+    one per coeff operand."""
+    seen = []
+
+    class Recorded(_Annotation):
+        def __init__(self, name, **meta):
+            super().__init__(name, **meta)
+            seen.append((name, self.meta))
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Recorded)
+    shape = (16, 128) if spec.dims == 2 else (8, 8, 128)
+    x = jnp.ones(shape, jnp.float32)
+    kw = {"source": x} if operands == ("source",) else \
+        {"aux": {n: x for n in operands}} if operands else {}
+    ops.stencil_sweep(x, spec, bx=128, bt=1, backend="interpret", **kw)
+    meta = dict(seen)["ops.sweep"]
+    assert (meta["bt"], meta["boundary"], meta["streams"]) == \
+        (1, boundary, streams)
+
+
 def test_service_records_one_flush_and_per_bucket_spans(traced):
     spec = diffusion(2, 1)
 
@@ -222,4 +258,6 @@ def test_profiler_host_plane_nests_program_spans(traced):
     assert by["ops.stencil_run"][3] == {"shape": "(16, 128)",
                                         "n_steps": 2}
     # A 16-row panel is one strip, and it reaches past both grid edges.
-    assert by["ops.sweep"][3] == {"bt": 2, "strip": 16, "edge_strips": 1}
+    assert by["ops.sweep"][3] == {"bt": 2, "boundary": "dirichlet0",
+                                  "streams": 1, "strip": 16,
+                                  "edge_strips": 1}
